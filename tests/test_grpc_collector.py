@@ -150,7 +150,7 @@ def test_report_backpressure_maps_to_resource_exhausted():
 
 
 def test_report_records_grpc_boundary_stage():
-    """Report must time its boundary under the obs taxonomy's
+    """Report must time its boundary under the obs catalogue's
     grpc_boundary stage — parity with the HTTP tier's http_boundary."""
     from zipkin_tpu import obs
 

@@ -143,6 +143,10 @@ def make_store(tmp_path, tag=""):
         wal_dir=str(tmp_path / f"wal{tag}"),
         archive_dir=str(tmp_path / f"archive{tag}"),
         sampling_budget=100.0,
+        # the tests tick the controller by hand; its own 5 s thread
+        # would otherwise publish at a moment of its choosing on a
+        # loaded machine (and outlive ``del victim``)
+        sampling_interval_s=3600.0,
     )
 
 

@@ -703,7 +703,7 @@ def test_zt08_recognizes_bare_record_import(tmp_path):
     )
 
 
-def test_zt08_clean_host_side_taxonomy_record(tmp_path):
+def test_zt08_clean_host_side_catalogue_record(tmp_path):
     result = lint(
         tmp_path,
         """
@@ -725,7 +725,7 @@ def test_zt08_clean_host_side_taxonomy_record(tmp_path):
 
 
 def test_zt08_flags_record_relayed_unknown_stage(tmp_path):
-    # the no-selfspan relay variant obeys the same closed taxonomy
+    # the no-selfspan relay variant obeys the same closed catalogue
     assert_rule_owned(
         tmp_path,
         """

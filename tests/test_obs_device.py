@@ -112,7 +112,7 @@ def test_status_shape_and_transfer_gauges():
     body = obs.status()
     assert body["enabled"] is True
     assert set(body["totals"]) == {"programs", "calls", "compiles",
-                                   "recompiles"}
+                                   "recompiles", "analysisFailures"}
     assert isinstance(body["hbm"], dict)  # {} on CPU backends
     assert body["transfers"]["count"] >= 0
     assert body["transfers"]["bytes"] >= 0

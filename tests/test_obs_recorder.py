@@ -189,7 +189,7 @@ class TestConfigAndSlowPath:
         assert rec.snapshot().total_count == 0
 
 
-class TestTaxonomy:
+class TestCatalogue:
     def test_budgets_cover_every_stage(self):
         assert set(stages_mod.DEFAULT_BUDGETS_US) == set(STAGES)
         assert all(v > 0 for v in stages_mod.DEFAULT_BUDGETS_US.values())

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from tests.fixtures import TODAY_US, lots_of_spans
-from zipkin_tpu.model.span import Endpoint, Span
+from zipkin_tpu.model.span import Endpoint, Kind, Span
 from zipkin_tpu.parallel.mesh import make_mesh
 from zipkin_tpu.storage.memory import InMemoryStorage
 from zipkin_tpu.tpu.state import AggConfig
@@ -76,6 +76,58 @@ class TestLinksSurviveEviction:
         assert got == want
         total_calls = sum(c for _, _, c, _ in got)
         assert total_calls > SMALL.ring_capacity  # provably beyond the ring
+
+    @pytest.mark.xfail(
+        strict=True,
+        reason="known gap (PR 22, ROADMAP 'link exactness across "
+        "eviction'): a rolled lane may be overwritten while a tree "
+        "neighbour is still unrolled, and that neighbour then links "
+        "without it",
+    )
+    @pytest.mark.parametrize(
+        "order",
+        [(0, 1, 2), (1, 0, 2, 3)],
+        ids=["rolled-region-edge-cuts-a-trace", "child-batch-before-parent-batch"],
+    )
+    def test_links_exact_when_a_relative_is_evicted_first(self, order):
+        """Batches of exactly rollup_segment lanes, every batch boundary
+        between a parent and its child. In arrival order the parent is
+        rolled (correctly silent: it has a child) and then overwritten by
+        a full-segment write before the child is rolled, so the child's
+        rule-6b backfill is lost; with the child's batch first, the child
+        is rolled and overwritten and the parent later counts as a leaf.
+        chip_smoke.py met both through the MP tier at the default size
+        (one edge off by one in about every second run); it now applies
+        one POST at a time, which at its sizes never overwrites a rolled
+        lane that an unrolled one still needs."""
+        seg = SMALL.rollup_segment
+        ep = [Endpoint.create(f"s{i}", f"10.0.0.{i + 1}") for i in range(3)]
+
+        def client(tid, sid, parent, a, b):
+            return Span.create(
+                trace_id=f"{tid:016x}", id=f"{sid:016x}",
+                parent_id=None if parent is None else f"{parent:016x}",
+                kind=Kind.CLIENT, name="op", local_endpoint=ep[a],
+                remote_endpoint=ep[b], timestamp=TODAY_US + tid, duration=10,
+            )
+
+        # one lone span, then parent/child pairs: every even lane
+        # boundary, so every batch boundary, cuts a pair
+        spans = [client(1, 1, None, 0, 1)]
+        for t in range(2, seg * len(order)):
+            spans.append(client(t, t << 4 | 1, None, 0, 1))
+            spans.append(client(t, t << 4 | 2, t << 4 | 1, 1, 2))
+        spans = spans[: seg * len(order)]
+        batches = [spans[i : i + seg] for i in range(0, len(spans), seg)]
+        store = TpuStorage(config=SMALL, mesh=make_mesh(1), pad_to_multiple=256)
+        oracle = InMemoryStorage(max_span_count=500_000)
+        oracle.accept(spans).execute()
+        for i in order:
+            store.accept(batches[i]).execute()
+        end_ts = TODAY_US // 1000 + 3_600_000
+        assert link_set(store, end_ts, WIDE_LOOKBACK) == link_set(
+            oracle, end_ts, WIDE_LOOKBACK
+        )
 
 
 def _two_hour_spans():

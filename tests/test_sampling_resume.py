@@ -34,6 +34,10 @@ def make(tmp_path):
         wal_dir=str(tmp_path / "wal"),
         archive_dir=str(tmp_path / "archive"),
         sampling_budget=100.0,
+        # the tests tick the controller by hand; its own 5 s thread
+        # would otherwise publish at a moment of its choosing on a
+        # loaded machine (and outlive ``del victim``)
+        sampling_interval_s=3600.0,
     )
 
 

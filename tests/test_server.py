@@ -225,7 +225,7 @@ class TestOps:
 
         run(scenario)
 
-    def test_metrics_taxonomy(self):
+    def test_metrics_catalogue(self):
         async def scenario(client):
             await client.post("/api/v2/spans", data=post_trace_body())
             body = await (await client.get("/metrics")).json()

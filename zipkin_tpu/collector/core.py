@@ -3,7 +3,7 @@
 Reference semantics: ``zipkin2/collector/Collector.java``,
 ``CollectorComponent.java``, ``CollectorSampler.java``,
 ``CollectorMetrics.java``, ``InMemoryCollectorMetrics.java`` (SURVEY.md
-§2.2, §3.2). The counter taxonomy (messages, messagesDropped, bytes, spans,
+§2.2, §3.2). The counter catalogue (messages, messagesDropped, bytes, spans,
 spansDropped) is kept name-for-name so dashboards translate.
 
 Sampling is **boundary sampling**: the decision is a pure function of the

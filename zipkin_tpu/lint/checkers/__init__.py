@@ -21,7 +21,7 @@ Rule catalog (grounded in real past regressions — see ARCHITECTURE.md
   query time.
 - ZT08 obs stage discipline: ``obs.record`` reachable from
   device-traced code (host instrumentation runs once at trace time),
-  or a stage argument outside the closed taxonomy in
+  or a stage argument outside the closed catalogue in
   ``obs/stages.py``.
 - ZT09 dispatch-critical loops: Python ``for``/``while``/comprehensions
   inside functions marked ``# zt-dispatch-critical`` — the ingest

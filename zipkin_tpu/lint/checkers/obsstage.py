@@ -1,7 +1,7 @@
 """ZT08 — flight-recorder stage discipline.
 
 The obs tier (``zipkin_tpu/obs``) is host-side instrumentation with a
-CLOSED stage taxonomy (``obs.stages.STAGES``): dashboards, budgets, and
+CLOSED stage catalogue (``obs.stages.STAGES``): dashboards, budgets, and
 the /statusz schema key off the fixed name set, and the recorder indexes
 histograms by ``STAGE_INDEX`` — an unknown name is a hot-path KeyError.
 Two shapes are flagged:
@@ -19,7 +19,7 @@ Two shapes are flagged:
    smear "traced" onto every same-named host method — precision rules
    ride resolved edges, fence rules keep the over-approximation.
 2. A ``record()`` stage argument that is not a string literal from the
-   taxonomy. Literal-only keeps every stage name greppable and lets
+   catalogue. Literal-only keeps every stage name greppable and lets
    this rule verify membership statically; a dynamic stage would also
    dodge the budget table. To add a stage, extend ``obs/stages.py``
    (name + budget) — see its docstring — and this rule learns it
@@ -29,7 +29,7 @@ Recognized record shapes: ``obs.record(...)``, ``RECORDER.record(...)``,
 ``obs.RECORDER.record(...)``, and a bare ``record(...)`` when the module
 imports it ``from zipkin_tpu.obs import record``. ``record_relayed`` —
 the no-selfspan variant the fan-out dispatcher uses for worker-measured
-stages — is held to the same discipline (literal taxonomy stage, host
+stages — is held to the same discipline (literal catalogue stage, host
 code only).
 
 The windowed-telemetry and device-observatory hooks (ISSUE 9) are host
@@ -116,7 +116,7 @@ class ObsStageDiscipline(Checker):
     name = "obs-stage-discipline"
     doc = (
         "obs.record inside device-traced code; stage args outside the "
-        "closed taxonomy"
+        "closed catalogue"
     )
     hint = (
         "record stages from host code only, with a string literal from "
@@ -196,7 +196,7 @@ class ObsStageDiscipline(Checker):
             return _root_name(f) in _HOOK_ROOTS
         return isinstance(f, ast.Name) and f.id in bare_hooks
 
-    # -- shape 2: stage names come from the closed taxonomy ----------------
+    # -- shape 2: stage names come from the closed catalogue ----------------
 
     def _check_stage_args(self, module: Module, records):
         for call in records:
@@ -213,7 +213,7 @@ class ObsStageDiscipline(Checker):
                     module,
                     call,
                     "record() stage must be a string literal — dynamic "
-                    "names dodge the taxonomy and the budget table",
+                    "names dodge the catalogue and the budget table",
                 )
                 continue
             if arg.value not in STAGES:

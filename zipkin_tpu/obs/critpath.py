@@ -60,7 +60,7 @@ WIRE_T0_NS: contextvars.ContextVar[int] = contextvars.ContextVar(
     "zipkin_tpu_wire_t0_ns", default=0
 )
 
-# -- segment taxonomy ----------------------------------------------------
+# -- segment catalogue ----------------------------------------------------
 # Stamped segments carry measured intervals; derived segments are the
 # gaps between them, classified by pipeline phase. ``kind`` drives the
 # queue-wait vs service rollup.

@@ -12,11 +12,13 @@ wrapped at build time in :func:`DeviceObservatory.wrap`, which captures:
   trace+compile — a *runtime recompile detector*. Steady state must
   show zero growth after warmup;
 - **``cost_analysis()`` / ``memory_analysis()`` at first compile**,
-  captured best-effort through an AOT ``lower().compile()`` of the same
-  arguments (one extra compile per program per process; disable with
-  ``TPU_OBS_DEVICE_ANALYSIS=0`` where compiles are expensive). The AOT
-  path does not populate the jit dispatch cache, so it never perturbs
-  the recompile detector;
+  captured through an AOT ``lower().compile()`` of the same arguments.
+  JAX serves that second compile from its in-process executable cache
+  (``analysisWallMs``: a millisecond or two per program on a v5e, where
+  the first compile took up to two minutes); a failure is counted
+  (``analysisFailures``), not hidden. Disable with
+  ``TPU_OBS_DEVICE_ANALYSIS=0``. The AOT path does not populate the jit
+  dispatch cache, so it never perturbs the recompile detector;
 - **live-HBM and host-transfer gauges**: accelerator
   ``memory_stats()`` (absent on CPU) and the readpack transfer
   count/bytes, surfaced next to the existing ``hostTransfers`` counter.
@@ -31,12 +33,15 @@ entries over a test run — reads merge them.
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from zipkin_tpu.obs import querytrace
+
+logger = logging.getLogger(__name__)
 
 
 class ProgramStats:
@@ -45,7 +50,7 @@ class ProgramStats:
     __slots__ = ("name", "calls", "compiles", "call_wall_s",
                  "compile_wall_s", "last_compile_s", "max_call_s",
                  "cache_size", "cost", "memory", "analysis_wall_s",
-                 "_analysis_tried", "_cache_size_fn")
+                 "analysis_error", "_analysis_tried", "_cache_size_fn")
 
     def __init__(self, name: str, fn: Callable) -> None:
         self.name = name
@@ -59,6 +64,7 @@ class ProgramStats:
         self.cost: Optional[Dict[str, float]] = None
         self.memory: Optional[Dict[str, int]] = None
         self.analysis_wall_s = 0.0
+        self.analysis_error: Optional[str] = None
         self._analysis_tried = False
         # private jax API, probed once; absent -> no recompile detection
         self._cache_size_fn = getattr(fn, "_cache_size", None)
@@ -125,8 +131,12 @@ class ProgramStats:
                         ma, "output_size_in_bytes", 0)),
                     "tempBytes": int(getattr(ma, "temp_size_in_bytes", 0)),
                 }
-        except Exception:
-            pass
+        except Exception as e:
+            # a debug gauge must not fail the dispatch it rides on, but
+            # the failure is counted where statusz shows it
+            self.analysis_error = f"{type(e).__name__}: {e}"[:200]
+            logger.warning("cost analysis of %s failed", self.name,
+                           exc_info=True)
 
     def as_dict(self) -> Dict:
         d: Dict = {
@@ -138,10 +148,14 @@ class ProgramStats:
             "lastCompileMs": round(self.last_compile_s * 1e3, 3),
             "maxCallMs": round(self.max_call_s * 1e3, 3),
         }
+        if self._analysis_tried:
+            d["analysisWallMs"] = round(self.analysis_wall_s * 1e3, 3)
         if self.cost is not None:
             d["cost"] = self.cost
         if self.memory is not None:
             d["memory"] = self.memory
+        if self.analysis_error is not None:
+            d["analysisError"] = self.analysis_error
         return d
 
 
@@ -202,15 +216,17 @@ class DeviceObservatory:
     # -- query side ----------------------------------------------------
 
     def totals(self) -> Dict[str, int]:
-        calls = compiles = recompiles = 0
+        calls = compiles = recompiles = failures = 0
         with self._lock:
             entries = [e for lst in self._programs.values() for e in lst]
         for e in entries:
             calls += e.calls
             compiles += e.compiles
             recompiles += e.recompiles
+            failures += e.analysis_error is not None
         return {"programs": len(self._programs), "calls": calls,
-                "compiles": compiles, "recompiles": recompiles}
+                "compiles": compiles, "recompiles": recompiles,
+                "analysisFailures": failures}
 
     def programs(self) -> Dict[str, Dict]:
         """Per-name merged view (several builds of one name sum up)."""
@@ -235,22 +251,38 @@ class DeviceObservatory:
                 merged["lastCompileMs"] = max(
                     merged["lastCompileMs"], d["lastCompileMs"])
                 merged["maxCallMs"] = max(merged["maxCallMs"], d["maxCallMs"])
+                if "analysisWallMs" in d:
+                    merged["analysisWallMs"] = round(
+                        merged.get("analysisWallMs", 0.0)
+                        + d["analysisWallMs"], 3)
                 if "cost" in d:
                     merged["cost"] = d["cost"]
                 if "memory" in d:
                     merged["memory"] = d["memory"]
+                if "analysisError" in d:
+                    merged["analysisError"] = d["analysisError"]
             out[name] = merged
         return out
 
-    def status(self) -> Dict:
-        """Full dict for the ``/statusz`` device section."""
+    def status(self, devices: Optional[Sequence] = None) -> Dict:
+        """Full dict for the ``/statusz`` device section. ``devices``
+        are the devices of the mesh the store actually uses: they name
+        the platform the answers came from, and bound the HBM gauges."""
+        import jax
+
         body = {
             "enabled": self._enabled,
             "analysis": self._analysis,
             "totals": self.totals(),
             "programs": self.programs(),
-            "hbm": hbm_stats(),
+            "hbm": hbm_stats(devices),
+            # the persistent compile cache JAX is using (None = off)
+            "compileCacheDir": jax.config.jax_compilation_cache_dir or None,
         }
+        if devices:
+            body["platform"] = devices[0].platform
+            body["deviceKind"] = devices[0].device_kind
+            body["count"] = len(devices)
         try:
             from zipkin_tpu import readpack
 
@@ -263,32 +295,34 @@ class DeviceObservatory:
         return body
 
 
-def hbm_stats() -> Dict:
-    """Live accelerator memory across local devices; ``{}`` where the
-    backend exposes no ``memory_stats()`` (CPU)."""
-    try:
+def hbm_stats(devices: Optional[Sequence] = None) -> Dict:
+    """Live accelerator memory, summed and per device, over ``devices``
+    (default: all local devices); ``{}`` where the backend exposes no
+    ``memory_stats()`` (CPU)."""
+    if devices is None:
         import jax
 
         devices = jax.local_devices()
-    except Exception:
-        return {}
-    in_use = limit = peak = 0
-    seen = 0
+    per_device = []
     for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
+        stats = d.memory_stats()
         if not stats:
             continue
-        seen += 1
-        in_use += int(stats.get("bytes_in_use", 0))
-        limit += int(stats.get("bytes_limit", 0))
-        peak += int(stats.get("peak_bytes_in_use", 0))
-    if not seen:
+        per_device.append({
+            "id": int(d.id),
+            "bytesInUse": int(stats.get("bytes_in_use", 0)),
+            "bytesLimit": int(stats.get("bytes_limit", 0)),
+            "peakBytesInUse": int(stats.get("peak_bytes_in_use", 0)),
+        })
+    if not per_device:
         return {}
-    return {"devices": seen, "bytesInUse": in_use, "bytesLimit": limit,
-            "peakBytesInUse": peak}
+    return {
+        "devices": len(per_device),
+        "bytesInUse": sum(d["bytesInUse"] for d in per_device),
+        "bytesLimit": sum(d["bytesLimit"] for d in per_device),
+        "peakBytesInUse": sum(d["peakBytesInUse"] for d in per_device),
+        "perDevice": per_device,
+    }
 
 
 def _env_on(name: str, default: str = "1") -> bool:

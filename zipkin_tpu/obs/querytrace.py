@@ -53,7 +53,7 @@ from typing import Callable, Dict, List, Optional
 from zipkin_tpu import obs as _obs
 from zipkin_tpu.obs.recorder import NUM_BUCKETS, bucket_le_us
 
-# -- segment taxonomy ----------------------------------------------------
+# -- segment catalogue ----------------------------------------------------
 # Stamped segments carry measured intervals; QSEG_OTHER is derived — the
 # gap sweep attributes every unstamped nanosecond of the query wall to
 # it, so conservation holds by construction and "other" shrinking is the

@@ -1,8 +1,8 @@
-"""Fixed stage taxonomy for the pipeline flight recorder.
+"""Fixed stage catalogue for the pipeline flight recorder.
 
 Every ``obs.record(stage, dur_s)`` call site must name one of the
 stages below with a string literal (statically enforced by lint rule
-ZT08). The taxonomy is deliberately closed: a fixed, ordered tuple
+ZT08). The catalogue is deliberately closed: a fixed, ordered tuple
 lets the recorder preallocate flat per-thread arrays indexed by stage,
 and dashboards can rely on the label set being stable across builds.
 

@@ -1,7 +1,7 @@
 """Resume supervisor: snapshot-and-exit-restartable on degraded windows.
 
 VERDICT r5: the flagship run died at 90.7% of 1B inside a degraded
-relay window — wire rate collapsed, the deadline passed, and nothing
+window — wire rate collapsed, the deadline passed, and nothing
 could persist the accumulated state and hand off to a fresh window.
 This module is that missing piece: it watches the ingest wire-rate
 against a rolling baseline of healthy windows, and when the rate stays

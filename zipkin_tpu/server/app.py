@@ -71,9 +71,13 @@ def build_storage(config: ServerConfig) -> StorageComponent:
     if config.storage_type == "mem":
         return InMemoryStorage(max_span_count=config.mem_max_spans, **common)
     if config.storage_type == "tpu":
+        from zipkin_tpu.parallel.mesh import enable_compile_cache
         from zipkin_tpu.storage.tpu import TpuStorage
         from zipkin_tpu.tpu.state import AggConfig
 
+        # before the first program is built: a restart must not pay the
+        # minutes of compile again
+        logger.info("compile cache: %s", enable_compile_cache() or "off")
         agg_kwargs = dict(config.tpu_agg)
         if config.tpu_sampling:
             # sampling is a STATIC AggConfig field (it changes the
@@ -149,19 +153,25 @@ class ZipkinServer:
         sampler = CollectorSampler(self.config.sample_rate)
         http_metrics = self.metrics.for_transport("http")
         self._mp_ingester = None
-        if self.config.tpu_mp_workers > 0:
+        core = getattr(self.storage, "delegate", self.storage)
+        if self.config.tpu_fast_ingest and hasattr(core, "ingest_json_fast"):
             from zipkin_tpu import native
+
+            # the fast path IS the native parser: without it every
+            # payload would quietly take the object path instead
+            if not native.available():
+                raise RuntimeError(
+                    "TPU_FAST_INGEST=true needs the native span parser "
+                    "(zipkin_tpu/native/span_json.c), and it could not "
+                    "be built or loaded: is a C compiler installed?"
+                )
+        if self.config.tpu_mp_workers > 0:
             from zipkin_tpu.tpu.store import TpuStorage as _CoreTpu
 
             # the MP tier needs the CORE store (it reaches the vocab and
             # aggregator directly); a throttle wrapper still exposes it
             # via .delegate
-            core = getattr(self.storage, "delegate", self.storage)
-            if (
-                isinstance(core, _CoreTpu)
-                and native.available()
-                and self.config.tpu_fast_ingest
-            ):
+            if isinstance(core, _CoreTpu) and self.config.tpu_fast_ingest:
                 from zipkin_tpu.tpu.mp_ingest import MultiProcessIngester
 
                 self._mp_ingester = MultiProcessIngester(
@@ -188,9 +198,9 @@ class ZipkinServer:
                 core.mp_ingester = self._mp_ingester
             else:
                 logger.warning(
-                    "TPU_MP_WORKERS=%d ignored: requires STORAGE_TYPE=tpu, "
-                    "the native codec, and TPU_FAST_INGEST=true (the MP "
-                    "tier is the fast path's scale-out)",
+                    "TPU_MP_WORKERS=%d ignored: requires STORAGE_TYPE=tpu "
+                    "and TPU_FAST_INGEST=true (the MP tier is the fast "
+                    "path's scale-out)",
                     self.config.tpu_mp_workers,
                 )
         self.collector = Collector(
@@ -1190,7 +1200,7 @@ class ZipkinServer:
             rec.add_source("critpath", cp.waterfall)
 
     async def get_metrics(self, request: web.Request) -> web.Response:
-        """Actuator-style counters, reference taxonomy kept verbatim:
+        """Actuator-style counters, reference catalogue kept verbatim:
         ``counter.zipkin_collector.spans.http`` etc."""
         out = {}
         for key, value in self.metrics.snapshot().items():
@@ -1468,7 +1478,13 @@ class ZipkinServer:
         # wall, first-compile cost/memory analysis, HBM + transfer gauges
         from zipkin_tpu.obs.device import OBSERVATORY
 
-        body["device"] = await asyncio.to_thread(OBSERVATORY.status)
+        # ... and which devices: those of the mesh the store runs on
+        agg = getattr(
+            getattr(self.storage, "delegate", self.storage), "agg", None)
+        body["device"] = await asyncio.to_thread(
+            OBSERVATORY.status,
+            list(agg.mesh.devices.flat) if agg is not None else None,
+        )
         # per-worker attribution table from the fan-out tier (ISSUE 9
         # satellite): dispatcher-side tallies keyed by widx
         ing = getattr(self.storage, "mp_ingester", None)

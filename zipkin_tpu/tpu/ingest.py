@@ -55,10 +55,13 @@ def _hll_update(registers, rows, hashes, valid):
     Measured ~11% faster than the XLA scatter on a v5e chip but <1% of
     the ingest step — see ops/pallas_hll.py for the evidence and why the
     XLA path stays the default."""
-    if (
-        os.environ.get("TPU_PALLAS_HLL", "") in ("1", "true")
-        and jax.default_backend() == "tpu"
-    ):
+    if os.environ.get("TPU_PALLAS_HLL", "") in ("1", "true"):
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "TPU_PALLAS_HLL=1 asks for the Pallas HLL kernel, which "
+                f"runs only on a TPU; the backend is "
+                f"{jax.default_backend()!r}. Unset it to take the XLA path."
+            )
         from zipkin_tpu.ops import pallas_hll
 
         return pallas_hll.update(registers, rows, hashes, valid)

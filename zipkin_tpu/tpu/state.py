@@ -24,7 +24,7 @@ SURVEY.md §2.3), the TPU tier keeps **fixed-shape aggregate state in HBM**
                  §2.3, §3.5) — links survive ring eviction, and
                  ``get_dependencies(endTs, lookback)`` merges live-ring
                  links with the buckets in the window.
-- ``counters`` — ingest telemetry (CollectorMetrics taxonomy, §2.2).
+- ``counters`` — ingest telemetry (CollectorMetrics catalogue, §2.2).
 
 The whole state is one NamedTuple pytree of arrays → trivially donatable,
 shard-able on a leading axis, and snapshot-able (tpu/snapshot.py).
