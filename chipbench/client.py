@@ -1,0 +1,338 @@
+"""The load client: a process of its own that offers one cell's traffic.
+
+Started by ``run.py`` with a spec file; never imports JAX or the program.
+Phases: build the templates from the seed; warm the device step at the cell's
+own POST size and fill the ring once; print ``WINDOW <monotonic>`` and offer
+the cell's traffic for ``seconds``; stop sending, wait until the server has
+applied every acknowledged span and the device has run every step; write
+every batch with its times to the result file. All clocks are this process's ``time.monotonic()``, which on
+Linux is one clock for every process of the machine, so ``run.py`` can
+compare them with its own.
+
+An operation is one batch. Its time runs from when it was first due to its
+202, 429s retried after the server's ``X-Retry-After-Ms`` included. The one
+loop kind so far is ``closed``: ``connections`` senders, each sending its next
+batch on the 202, which finds the pace where the configuration's 202 means
+applied. A workload file that asks for another kind is refused: the PR that
+proves such a cell adds the loop.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import threading
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import gen  # noqa: E402
+from launcher import Http  # noqa: E402
+
+DRAIN_LIMIT_S = 90.0  # an answer that comes late is late, not wrong
+OP_TIMEOUT_S = 120.0
+WARM_ROUNDS = 4
+
+
+class Client:
+    def __init__(self, spec: dict) -> None:
+        self.spec = spec
+        self.port = spec["port"]
+        self.cfg = spec["config"]
+        self.wl = spec["workload"]
+        self.posts = self.wl["posts"]
+        if self.posts["loop"] != "closed":
+            raise ValueError(f"loop kind {self.posts['loop']!r}: only 'closed' "
+                             "is built so far (chipbench/README.md)")
+        self.traffic = gen.Traffic(spec["seed"], self.cfg["fleet"], self.posts)
+        self.post_spans = self.traffic.post_spans
+        self.lock = threading.Lock()
+        self.next_n = 0
+        self.sends = []  # dicts, one per batch
+
+    # ---- writes ----------------------------------------------------------
+
+    def take_n(self) -> int:
+        with self.lock:
+            n = self.next_n
+            self.next_n += 1
+            return n
+
+    def send_batch(self, http: Http, scratch: dict, n: int, due: float,
+                   give_up_at: float, phase: str) -> dict:
+        body = self.traffic.body(n, scratch)
+        rec = {"n": n, "template": self.traffic.template_of(n), "due": due,
+               "phase": phase, "retries": 0, "status": None, "acked": None}
+        while True:
+            try:
+                status, text, headers = http.request(
+                    "POST", "/api/v2/spans", body,
+                    {"Content-Type": "application/json"})
+            except OSError as e:
+                rec["status"] = f"{type(e).__name__}: {e}"
+                break
+            now = time.monotonic()
+            if status == 202:
+                rec["status"], rec["acked"] = 202, now
+                break
+            if status != 429 or now > give_up_at:
+                rec["status"] = status
+                rec["error"] = text[:200].decode("utf-8", "replace")
+                break
+            rec["retries"] += 1
+            wait_ms = headers.get("X-Retry-After-Ms")
+            time.sleep(float(wait_ms) / 1000.0 if wait_ms
+                       else min(0.005 * rec["retries"], 0.25))
+        with self.lock:
+            self.sends.append(rec)
+        return rec
+
+    def closed_loop(self, count: int = None, until: float = None,
+                    give_up_at: float = None, phase: str = "window") -> None:
+        """``connections`` senders, each sending its next batch on the 202.
+        Ends after ``count`` batches in all, or at ``until``."""
+        left = [count]
+
+        def worker() -> None:
+            http, scratch = Http(self.port, OP_TIMEOUT_S), {}
+            while True:
+                with self.lock:
+                    if count is not None:
+                        if left[0] <= 0:
+                            break
+                        left[0] -= 1
+                if until is not None and time.monotonic() >= until:
+                    break
+                self.send_batch(http, scratch, self.take_n(), time.monotonic(),
+                                give_up_at or time.monotonic() + 900.0, phase)
+            http.close()
+
+        threads = [threading.Thread(target=worker)
+                   for _ in range(int(self.posts["connections"]))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+
+    # ---- the applied-span counter ------------------------------------------
+
+    def wait_applied(self, want: int, limit_s: float) -> float:
+        """Until the server's applied-span counter has reached ``want``."""
+        http = Http(self.port, OP_TIMEOUT_S)
+        c = self.cfg["applied_counter"]
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < limit_s:
+            if int(http.get_json(c["path"]).get(c["key"], 0)) >= want:
+                http.close()
+                return time.monotonic()
+            time.sleep(0.02)
+        http.close()
+        return float("nan")
+
+    def device_sync(self, t_counted: float) -> float:
+        """When the device has run every step fed to it. The applied-span
+        counter moves when a step is handed to the device's queue, and the
+        host runs seconds ahead of the device (PERF.md section 6), so the
+        window's work is done only when a read that has to follow the queued
+        steps comes back: the configuration's ``device_sync``, a fresh read
+        under a key nobody asked for (``{nonce}``), so that no cache and no
+        mirror answers it. -> the later of the two moments."""
+        path = self.cfg.get("device_sync")
+        if not path or t_counted != t_counted:
+            return t_counted
+        http = Http(self.port, OP_TIMEOUT_S)
+        nonce = f"0.9{1000 + (self.spec['seed'] * 7919 + self.next_n) % 9000}"
+        http.get_json(path.replace("{nonce}", nonce))
+        http.close()
+        return time.monotonic()
+
+    def snapshot(self) -> dict:
+        """Stage table, counters and the program table, for the per-layer
+        readers' deltas and the count of compiles inside the window."""
+        http = Http(self.port, OP_TIMEOUT_S)
+        statusz = http.get_json("/api/v2/tpu/statusz")
+        out = {"t": time.monotonic(), "stages": statusz.get("stages", {}),
+               "device_totals": statusz.get("device", {}).get("totals", {}),
+               "program_calls": {
+                   n: [p.get("calls", 0), p.get("compiles", 0)] for n, p in
+                   statusz.get("device", {}).get("programs", {}).items()},
+               "counters": http.get_json("/api/v2/tpu/counters")}
+        http.close()
+        return out
+
+    # ---- set-up: every step program of this POST size, then the ring ------
+
+    def program_calls(self, http: Http) -> dict:
+        progs = http.get_json("/api/v2/tpu/statusz")["device"]["programs"]
+        return {name: p.get("calls", 0) for name, p in progs.items()}
+
+    def post_applied(self, count: int) -> None:
+        """``count`` batches at the cell's own pace (its loop, its
+        connections: what the window can reach, set-up can), one step each,
+        then wait until all are applied."""
+        if count > 0:
+            self.closed_loop(count=count, phase="fill")
+        bad = [s for s in self.sends if s["status"] != 202]
+        if bad:
+            raise RuntimeError(f"set-up: {len(bad)} batches refused: {bad[0]}")
+        self.wait_applied(self.next_n * self.post_spans, 900.0)
+
+    def since_rollup(self) -> int:
+        """Batches since the half-ring was last rolled up, from this client's
+        own count: it has sent every span the server holds, all batches are
+        of one size, and the roll-up is due with the first batch that would
+        take the count past ``ring_capacity / 2``."""
+        per_roll = self.cfg["agg"]["ring_capacity"] // 2 // self.post_spans
+        return (self.next_n - 1) % per_roll + 1 if self.next_n else 0
+
+    def after_publish(self, http: Http, watch: str) -> None:
+        """Until the program ``watch`` has run once more (the configuration
+        names one that only the read mirror's publish calls), 15 s at most:
+        the next publish is then a period away."""
+        seen = self.program_calls(http).get(watch, 0)
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 15.0:
+            time.sleep(0.2)
+            if self.program_calls(http).get(watch, 0) > seen:
+                return
+
+    def warm_steps(self):
+        """Drive the write path at the cell's own POST size so that no variant
+        of the device step compiles (or is read from the compile cache, which
+        also takes seconds) inside the window.
+        -> (rounds, hinted programs never reached).
+
+        Whatever the program, this sends one batch and then goes on to the
+        ring fill, which alone takes the step through two roll-ups. Where the
+        configuration's ``warm`` block gives hints, it steers first. Today's
+        program folds due maintenance into the step, so the step comes in
+        four variants: plain, with the digest buffer's flush, with the
+        half-ring roll-up, with both. Which a batch takes depends on lanes
+        since the last roll-up (this client's own count) and lanes since the
+        last flush (a fresh percentile read, ``flush_by``, zeroes it; so does
+        every publish of the read mirror, which is why the burst that is to
+        fill the buffer starts right after one). ``step_programs`` names the
+        variants as ``statusz`` lists them, only so that the loop knows when
+        to stop: a name that never shows up costs one round, then the loop
+        ends for lack of progress, and nothing fails. A configuration for a
+        program with one step shape leaves ``warm`` out."""
+        warm = self.cfg.get("warm") or {}
+        http = Http(self.port, 900.0)
+        self.post_applied(1)
+        wanted = warm.get("step_programs") or []
+        if not wanted:
+            http.close()
+            return 0, []
+        agg = self.cfg["agg"]
+        per_roll = agg["ring_capacity"] // 2 // self.post_spans
+        per_flush = agg["digest_buffer"] // self.post_spans
+        missing, rounds = list(wanted), 0
+        for rounds in range(1, WARM_ROUNDS + 1):
+            calls = self.program_calls(http)
+            missing = [p for p in wanted if not calls.get(p)]
+            if not missing or (rounds > 1 and len(missing) == len(wanted)):
+                break  # all reached, or the hints name nothing of this program
+            to_edge = per_roll - self.since_rollup()  # batches that still fit
+            if any(p.endswith("rollup") for p in missing):
+                # to the edge, flush by a read, one more: the roll-up alone.
+                # Both counts now run together, and a burst of per_roll more
+                # ends in flush-and-roll-up, unless a publish flushes in
+                # between: so start right after one
+                self.post_applied(to_edge)
+                both = any("flush" in p for p in missing)
+                if both and warm.get("publish_program"):
+                    self.after_publish(http, warm["publish_program"])
+                http.get_json(warm["flush_by"])
+                self.post_applied(1)
+                if both:
+                    self.post_applied(min(per_roll, per_flush))
+            else:
+                # the flush alone: zero its count away from the edge
+                if to_edge in (0, per_roll):
+                    self.post_applied(1)
+                http.get_json(warm["flush_by"])
+                self.post_applied(per_flush + 1)
+        http.close()
+        return rounds, missing
+
+    def pin_phase(self) -> int:
+        """Leave the maintenance in one known phase before the window opens,
+        whatever the warm-up needed: half a roll-up period since the last
+        roll-up and, where the configuration says how, nothing pending since
+        the last flush. Runs that started in different phases settled into
+        rates 5-10% apart (PERF.md section 6). -> batches sent."""
+        per_roll = self.cfg["agg"]["ring_capacity"] // 2 // self.post_spans
+        http = Http(self.port, 900.0)
+        pad = (per_roll // 2 - self.since_rollup()) % per_roll
+        self.post_applied(pad)
+        flush_by = (self.cfg.get("warm") or {}).get("flush_by")
+        if flush_by:
+            http.get_json(flush_by)
+        http.close()
+        return pad
+
+    # ---- the run -----------------------------------------------------------
+
+    def wait_health(self) -> None:
+        """The client starts beside the server and builds its templates
+        while the server boots; then it waits for it."""
+        probe = Http(self.port, 5.0)
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 900.0:
+            try:
+                if probe.request("GET", "/health")[0] == 200:
+                    probe.close()
+                    return
+            except OSError:
+                probe.close()
+            time.sleep(0.25)
+        raise RuntimeError("/health did not answer")
+
+    def run(self) -> dict:
+        seconds = float(self.spec["seconds"])
+        self.wait_health()
+        # set-up: every step variant at the cell's own POST size, then the
+        # rest of one ring's worth, so the window runs with eviction
+        warm_rounds, warm_missing = self.warm_steps()
+        fill = -(-int(self.wl["fill_spans"]) // self.post_spans)
+        self.post_applied(max(0, fill - self.next_n))
+        self.pin_phase()
+        # the window starts with the device's queue empty, and the read that
+        # will close it has run (and compiled) once
+        self.device_sync(time.monotonic())
+        before = self.snapshot()
+        t0 = time.monotonic()
+        print(f"WINDOW {t0!r}", flush=True)
+        give_up_at = t0 + seconds + DRAIN_LIMIT_S
+        self.closed_loop(until=t0 + seconds, give_up_at=give_up_at)
+        t_close = time.monotonic()
+        acked = sum(1 for s in self.sends if s["status"] == 202)
+        t_drained = self.wait_applied(acked * self.post_spans,
+                                      max(1.0, give_up_at - time.monotonic()))
+        t_drained = self.device_sync(t_drained)
+        after = self.snapshot()
+        print(f"DRAINED {t_drained!r}", flush=True)
+        return {
+            "t0": t0, "t_close": t_close, "t_drained": t_drained,
+            "seconds": seconds, "post_spans": self.post_spans,
+            "sends": sorted(self.sends, key=lambda s: s["n"]),
+            "before": before, "after": after,
+            "drain_limit_s": DRAIN_LIMIT_S,
+            "warm_rounds": warm_rounds, "warm_missing": warm_missing,
+        }
+
+
+def main() -> int:
+    with open(sys.argv[1]) as f:
+        spec = json.load(f)
+    out = Client(spec).run()
+    tmp = spec["out"] + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump(out, f)
+    os.replace(tmp, spec["out"])
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,30 @@
+"""Host-clock stage time from the server's stage table (statusz), as the
+change over the window: sum of ``sumUs`` of ``stages`` over ``per``:
+``count`` (the first stage's own count), ``spans`` (spans applied in the
+window) or ``window`` (seconds of window plus drain, giving a share)."""
+
+
+def read(ctx, params):
+    before, after = ctx["result"]["before"], ctx["result"]["after"]
+    d_us, d_n = 0.0, 0
+    for i, stage in enumerate(params["stages"]):
+        a, b = after["stages"].get(stage), before["stages"].get(stage)
+        if a is None or b is None:
+            return None
+        d_us += a["sumUs"] - b["sumUs"]
+        if i == 0:
+            d_n = a["count"] - b["count"]
+    if d_us <= 0:
+        return None  # the recorder saw nothing of this stage
+    per = params["per"]
+    if per == "count":
+        den = d_n
+    elif per == "spans":
+        den = (after["counters"]["spans"] - before["counters"]["spans"])
+    elif per == "window":
+        den = ctx["window_s"] * 1e6
+    else:
+        raise ValueError(per)
+    if den <= 0:
+        return None
+    return d_us / den * params.get("scale", 1.0)
