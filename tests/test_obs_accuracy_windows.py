@@ -332,6 +332,36 @@ def test_rollup_detects_hll_drift():
     )
 
 
+def test_rollup_names_itself_in_the_lock_ledger():
+    """The rollup's device reads each take the aggregator lock: they run
+    under the holder label ``accuracy_rollup``, not ``unattributed``."""
+    from zipkin_tpu.obs import querytrace
+
+    n = 96
+    durs = np.full(n, 5_000)
+    shadow = HostShadow(link_rate=1.0, seed=8)
+    shadow.offer_cols(_client_server_lanes(n, durs))
+    vocab = FakeVocab([(0, 0), (1, 0), (2, 0)],
+                      {1: "frontend", 2: "backend"})
+    seen = []
+
+    class LabelledAgg(DeviceAgg):
+        def merged_digest(self):
+            seen.append(querytrace.current_label())
+            return super().merged_digest()
+
+        def dependency_edges(self, lo, hi):
+            seen.append(querytrace.current_label())
+            return super().dependency_edges(lo, hi)
+
+    agg = LabelledAgg(durs, distinct=n, edges=[(1, 2)], max_services=64,
+                      spans=2 * n)
+    AccuracyEstimator(DeviceStore(agg, vocab, 64), shadow,
+                      rollup_s=0.0).rollup()
+    assert seen and set(seen) == {"accuracy_rollup"}
+    assert querytrace.current_label() == "unattributed"
+
+
 def test_digest_quantile_midpoint_interpolation():
     rows = np.zeros((1, 4, 2))
     rows[0, :, 0] = [10.0, 20.0, 30.0, 40.0]

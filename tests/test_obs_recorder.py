@@ -205,5 +205,7 @@ class TestCatalogue:
             "wire_to_durable",
             "query_lock_wait", "query_wall", "query_mirror",
             "mirror_publish", "reader_serve",
+            # PR 26: the publish's hold, split, and the write path's wait
+            "publish_lock_hold", "publish_queue_drain", "ingest_lock_wait",
         }
         assert set(STAGES) == expected

@@ -30,7 +30,8 @@ Recognized record shapes: ``obs.record(...)``, ``RECORDER.record(...)``,
 imports it ``from zipkin_tpu.obs import record``. ``record_relayed`` —
 the no-selfspan variant the fan-out dispatcher uses for worker-measured
 stages — is held to the same discipline (literal catalogue stage, host
-code only).
+code only), and so is ``span`` — ``record`` as a context manager that also
+opens a profiler annotation: ``with obs.span("stage", key=...):``.
 
 The windowed-telemetry and device-observatory hooks (ISSUE 9) are host
 instrumentation too: ``WINDOWS.tick()`` / ``tick_if_due()`` mutate ring
@@ -52,7 +53,7 @@ from zipkin_tpu.obs.stages import STAGES
 
 _FUNC_KINDS = (ast.FunctionDef, ast.AsyncFunctionDef)
 
-_RECORD_ATTRS = {"record", "record_relayed"}
+_RECORD_ATTRS = {"record", "record_relayed", "span"}
 _RECORD_ROOTS = {"obs", "RECORDER"}
 # windows/device/shadow hooks: host-only for the same reason record is;
 # flagged by the traced-reach pass but exempt from stage-arg validation

@@ -6,6 +6,11 @@ call ``obs.record(stage, dur_s)`` with a stage-name literal from
 and that no record call hides inside jit'd/device-traced code).
 Disable with ``TPU_OBS=0`` — every record becomes one predicate check.
 
+``obs.span(stage, **attrs)`` is ``record`` as a context manager around the
+timed block, which also puts the block on the profiler's clock as a trace
+event ``zt.<stage>`` (``jax.profiler.TraceAnnotation``; only in a process
+that has imported JAX already: see :mod:`zipkin_tpu.obs.recorder`).
+
 ``record_relayed`` is the histogram-only sibling for stage walls
 measured elsewhere (worker processes) and relayed to the recording
 thread — no budget/self-span path, so relayed time is never B3-linked
@@ -39,4 +44,5 @@ RECORDER = StageRecorder(
 )
 
 record = RECORDER.record
+span = RECORDER.span
 record_relayed = RECORDER.record_relayed

@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from zipkin_tpu import obs
+from zipkin_tpu.obs import querytrace
 from zipkin_tpu.obs.shadow import HostShadow
 from zipkin_tpu.ops import hll, ttmerge
 from zipkin_tpu.ops.tdigest import cluster_q_width
@@ -179,13 +180,18 @@ class AccuracyEstimator:
         windowed_detail: Dict = {}
 
         if not suppressed:
-            (services, p50_err, p99_err, p99_bound,
-             p50_drift, p99_drift) = self._digest_errors()
-            hll_err, hll_bound, distinct_detail = self._hll_error()
-            (w_digest_err, w_digest_drift, w_hll_err, w_hll_drift,
-             windowed_detail) = self._windowed_errors()
-            recall, links_detail = self._link_recall()
-            ret_bias = self._retention_bias()
+            # each of these device reads takes the aggregator lock and
+            # waits out the device's queue: the lock ledger names them
+            # (they read `unattributed`, and were taken for the mirror's
+            # publish: PERF.md section 6, PR 26)
+            with querytrace.lock_label("accuracy_rollup"):
+                (services, p50_err, p99_err, p99_bound,
+                 p50_drift, p99_drift) = self._digest_errors()
+                hll_err, hll_bound, distinct_detail = self._hll_error()
+                (w_digest_err, w_digest_drift, w_hll_err, w_hll_drift,
+                 windowed_detail) = self._windowed_errors()
+                recall, links_detail = self._link_recall()
+                ret_bias = self._retention_bias()
 
         self.rollups += 1
         roll_ms = (time.perf_counter() - t0) * 1000.0

@@ -1,10 +1,11 @@
 """Fixed stage catalogue for the pipeline flight recorder.
 
-Every ``obs.record(stage, dur_s)`` call site must name one of the
-stages below with a string literal (statically enforced by lint rule
-ZT08). The catalogue is deliberately closed: a fixed, ordered tuple
-lets the recorder preallocate flat per-thread arrays indexed by stage,
-and dashboards can rely on the label set being stable across builds.
+Every ``obs.record(stage, dur_s)`` and ``obs.span(stage)`` call site
+must name one of the stages below with a string literal (statically
+enforced by lint rule ZT08). The catalogue is deliberately closed: a
+fixed, ordered tuple lets the recorder preallocate flat per-thread
+arrays indexed by stage, and dashboards can rely on the label set being
+stable across builds.
 
 To add a stage: append the name here, give it a budget in
 ``DEFAULT_BUDGETS_US``, and instrument the host-side call site —
@@ -26,7 +27,7 @@ STAGES = (
     "pack",              # parsed spans → packed device wire image
     "route",             # shard routing of a fused batch
     "device_dispatch",   # enqueue wall of the jit'd ingest step (async dispatch)
-    "rollup",            # fused rollup dispatch wall (pre-eviction linking)
+    "rollup",            # device time of a roll-up-fused step (relayed by obs/device.py's clock)
     "ctx_advance",       # incremental link-context advance at query time
     "wal_append",        # WAL record write incl. buffer flush
     "wal_fsync",         # the fsync portion of a WAL append
@@ -49,6 +50,9 @@ STAGES = (
     "query_mirror",      # lock-free serve from the epoch-published read mirror
     "mirror_publish",    # one mirror publish: lock once, packed reads, swap
     "reader_serve",      # reader-process serve from the shm mirror segment
+    "publish_lock_hold",  # mirror publish: the body of its one lock hold, wait excluded
+    "publish_queue_drain",  # ... of which: lock taken -> the steps queued before it have run
+    "ingest_lock_wait",  # ingest_fused: its own wait for the aggregator lock
 )
 
 NUM_STAGES = len(STAGES)
@@ -85,6 +89,11 @@ DEFAULT_BUDGETS_US = {
     "query_mirror": 10_000,
     "mirror_publish": 1_000_000,
     "reader_serve": 10_000,
+    # the publish's reads queue behind every step the host has handed the
+    # device (seconds at saturation, PERF.md section 5), and ingest waits
+    "publish_lock_hold": 10_000_000,
+    "publish_queue_drain": 10_000_000,
+    "ingest_lock_wait": 10_000_000,
 }
 
 assert set(DEFAULT_BUDGETS_US) == set(STAGES)

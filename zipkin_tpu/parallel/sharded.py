@@ -529,17 +529,22 @@ def _compiled_programs(config: AggConfig, mesh: Mesh):
     # program counts calls/compiles through a thin wrapper — the runtime
     # recompile detector. The raw variants stay unwrapped (parity-test
     # only, never dispatched in production).
+    # Programs that return the state hand it on to the next call, which
+    # donates it: their completion token is a marker behind the call
+    # (token="state"); the step variants are counted apart, plain
+    # against maintenance-fused.
     _w = obs_device.OBSERVATORY.wrap
-    init = _w("spmd_init", init)
+    init = _w("spmd_init", init, token="state")
     step_variants = {
         k: _w("spmd_step" + ("_flush" if k[0] else "")
-              + ("_rollup" if k[1] else ""), v)
+              + ("_rollup" if k[1] else ""), v, token="state",
+              step="fused" if any(k) else "plain")
         for k, v in step_variants.items()
     }
     links = _w("spmd_links", links)
     merge = _w("spmd_merge", merge)
-    flush = _w("spmd_flush", flush)
-    rollup = _w("spmd_rollup", rollup)
+    flush = _w("spmd_flush", flush, token="state")
+    rollup = _w("spmd_rollup", rollup, token="state")
     whist = _w("spmd_whist", whist)
     digest_read = _w("spmd_digest_read", digest_read)
     edges = _w("spmd_edges", edges)
@@ -648,9 +653,10 @@ class ShardedAggregator:
         }
         # Incremental link-ctx maintenance telemetry (/metrics gauges
         # ctxDeltaLanes / ctxMaintenanceMs / ctxAdvances): advances run
-        # fused inside the rollup dispatch, so the ms figure is the HOST
-        # WALL of the last ctx-advancing dispatch (async — the device
-        # cost lives in the rollup budget, see benchmarks/query_slo.py).
+        # fused inside the rollup dispatch, so the ms figure is the
+        # DEVICE time of the last ctx-advancing program (the roll-up-
+        # fused step, or rollup_now's), as the completion clock of
+        # obs/device.py saw it run: see _maintenance_done.
         self.ctx_stats = {"ctx_advances": 0, "ctx_maintenance_ms": 0.0}
         # write-ahead log seam (tpu/wal.py): when set, every fused batch
         # is logged inside the state lock and wal_seq records the last
@@ -677,9 +683,8 @@ class ShardedAggregator:
         """Route one host batch across shards and fold it in (the batch
         ships as one fused u32 array — one transfer, not 17)."""
         live_ts = cols.ts_min[cols.valid]
-        t0 = time.perf_counter()
-        routed = route_fused(cols, self.n_shards)
-        obs.record("route", time.perf_counter() - t0)
+        with obs.span("route"):
+            routed = route_fused(cols, self.n_shards)
         self.ingest_fused(
             routed,
             n_spans=int(cols.valid.sum()),
@@ -713,7 +718,12 @@ class ShardedAggregator:
                 f"({self.config.rollup_segment}); chunk before ingest"
             )
         device_batch = jax.device_put(fused, self._sharding)
+        # the write path's own wait for the lock (query_lock_wait and the
+        # ledger count every outermost wait, the readers' with it); about
+        # 0 where this thread holds the lock already (WAL replay)
+        lock_wait = obs.span("ingest_lock_wait").start()
         with self.lock:
+            lock_wait.stop()
             # contention-ledger attribution: this hold is the write path
             self.lock.relabel("ingest_fused")
             # fold due maintenance into ONE fused dispatch with the step
@@ -721,21 +731,26 @@ class ShardedAggregator:
             need_rollup = (
                 self._lanes_since_rollup + lanes > self.config.rollup_segment
             )
-            t0 = time.perf_counter()
-            self.state = self._step_variants[(need_flush, need_rollup)](
-                self.state, device_batch
+            step = self._step_variants[(need_flush, need_rollup)]
+            seq = self.host_counters["batches"] + 1
+            obs_device.tag_next(
+                lanes, seq, self._maintenance_done if need_rollup else None
             )
-            # host wall of the enqueue (async dispatch: this is the cost
-            # ingest actually pays, consistent with ctx_maintenance_ms)
-            step_wall = time.perf_counter() - t0
-            obs.record("device_dispatch", step_wall)
+            # the enqueue wall of an async dispatch: what ingest pays on
+            # the host. The step's time ON the device is the completion
+            # clock's (obs/device.py), under the same seq.
+            with obs.span("device_dispatch", variant=step.__name__,
+                          lanes=lanes, seq=seq) as dispatch:
+                # the variant in the event's NAME too: every step is
+                # jit_spmd on the device plane, and a trace reader that
+                # prints names alone (chipbench/xplane.py --dump) shows it
+                with dispatch.child(step.__name__):
+                    self.state = step(self.state, device_batch)
             if need_flush:
                 self._pend_lanes = 0
             if need_rollup:
                 self._lanes_since_rollup = 0
                 self.ctx_stats["ctx_advances"] += 1
-                self.ctx_stats["ctx_maintenance_ms"] = step_wall * 1000.0
-                obs.record("rollup", step_wall)
             self._pend_lanes += lanes
             self._lanes_since_rollup += lanes
             self.write_version += 1
@@ -798,6 +813,14 @@ class ShardedAggregator:
                 self.wal_seq = self.wal_hook(
                     fused, n_spans, n_dur, n_err, ts_range
                 )
+
+    def _maintenance_done(self, device_s: float) -> None:  # zt-lint: disable=ZT04 — runs on the completion clock's thread, which must never take self.lock; one GIL-atomic store into a debug gauge
+        """A roll-up (fused into a step, or ``rollup_now``'s own) has run:
+        its time ON the device, from the completion clock's thread. With
+        the device observatory off nothing reports, and the gauge stays
+        where it was."""
+        self.ctx_stats["ctx_maintenance_ms"] = device_s * 1000.0
+        obs.record_relayed("rollup", device_s)
 
     @property
     def lane_cap(self) -> int:
@@ -1061,13 +1084,10 @@ class ShardedAggregator:
         the persistent incremental link ctx) and reset the write-distance
         tracker. Public for tests and shutdown paths."""
         with self.lock:
-            t0 = time.perf_counter()
+            obs_device.tag_next(on_done=self._maintenance_done)
             self.state = self._rollup(self.state)
             self._lanes_since_rollup = 0
             self.ctx_stats["ctx_advances"] += 1
-            self.ctx_stats["ctx_maintenance_ms"] = (
-                time.perf_counter() - t0
-            ) * 1000.0
             self.write_version += 1
             self._wal_marker("ttroll")
 
@@ -1221,3 +1241,5 @@ class ShardedAggregator:
     def block_until_ready(self) -> None:
         with self.lock:
             jax.tree_util.tree_map(lambda a: a.block_until_ready(), self.state)
+        # ... and until the completion clock has booked what has run
+        obs_device.OBSERVATORY.queue.wait_idle(10.0)

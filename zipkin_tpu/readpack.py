@@ -86,11 +86,12 @@ def device_get(x) -> np.ndarray:
         _transfers += 1
     import jax
 
-    t0 = time.perf_counter_ns()
-    out = np.asarray(jax.device_get(x))
-    t1 = time.perf_counter_ns()
-    obs.record("readpack_transfer", (t1 - t0) / 1e9)
-    querytrace.stamp_active(querytrace.QSEG_READPACK_TRANSFER, t0, t1)
+    with obs.span("readpack_transfer") as pull:
+        out = np.asarray(jax.device_get(x))
+    querytrace.stamp_active(
+        querytrace.QSEG_READPACK_TRANSFER,
+        int(pull.t0 * 1e9), int(pull.t1 * 1e9),
+    )
     with _counter_lock:
         _transfer_bytes += out.nbytes
     return out

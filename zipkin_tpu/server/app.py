@@ -817,7 +817,16 @@ class ZipkinServer:
     # entrypoint — tenant identity is extracted from X-Tenant-Id here,
     # before the collector chokepoint runs admission
     async def _ingest(self, request: web.Request, *, v1: bool) -> web.Response:
-        t0 = time.perf_counter()
+        # body read → collector hand-off complete; the stage counts the
+        # POSTs that end in the 202 ack, as it always has
+        with obs.span("http_boundary") as boundary:
+            response = await self._accept(request, v1, boundary.t0)
+            if response.status != 202:
+                boundary.drop()
+        return response
+
+    async def _accept(self, request: web.Request, v1: bool,
+                      t0: float) -> web.Response:
         # critpath wire anchor: the same instant http_boundary measures
         # from, in the ns domain the interval ledger uses. Contextvars
         # survive asyncio.to_thread, so the MP submit path reads it.
@@ -872,8 +881,6 @@ class ZipkinServer:
             return web.Response(
                 status=429, text=str(e), headers=self._backoff_headers(e)
             )
-        # body read → collector hand-off complete; the 202 ack follows
-        obs.record("http_boundary", time.perf_counter() - t0)
         return web.Response(status=202)
 
     # -- query -------------------------------------------------------------
@@ -1212,7 +1219,7 @@ class ZipkinServer:
             for name, value in restore.items():
                 out[f"gauge.zipkin_tpu.{name}"] = value
         # incremental link-ctx gauges (ISSUE 5): since-rollup delta size,
-        # advance count, and host wall of the last ctx-advancing dispatch
+        # advance count, and device time of the last ctx-advancing program
         counters = None
         if hasattr(self.storage, "ingest_counters"):
             counters = await asyncio.to_thread(self.storage.ingest_counters)

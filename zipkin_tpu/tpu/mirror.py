@@ -65,6 +65,7 @@ from contextlib import nullcontext
 from typing import Callable, Dict, Optional, Tuple
 
 from zipkin_tpu import obs
+from zipkin_tpu.obs import device as obs_device
 from zipkin_tpu.obs import querytrace
 
 logger = logging.getLogger(__name__)
@@ -293,25 +294,43 @@ class ReadMirror:
         ):
             self.publish_skips += 1
             return False
-        t0 = time.perf_counter()
         values: Dict[str, object] = {}
         lock = getattr(agg, "lock", None)
-        with querytrace.lock_label("mirror_publish"):
+        with obs.span("mirror_publish") as epoch, \
+                querytrace.lock_label("mirror_publish"):
             # the ONE lock hold of the epoch; the read programs below
             # re-enter it (counted, never measured — an RLock re-acquire
             # by its holder cannot block)
             with (lock if lock is not None else nullcontext()):
-                version = getattr(agg, "write_version", 0)
-                for key, ent in entries:
-                    try:
-                        values[key] = ent[0]()
-                    except Exception:
-                        # one bad closure (e.g. a window that aged out)
-                        # must not abort the epoch or kill the ticker
-                        logger.exception(
-                            "mirror publish: compute for %r failed", key
-                        )
-        publish_ms = (time.perf_counter() - t0) * 1000.0
+                # publish_lock_hold is the hold alone, the wait for the
+                # lock excluded. Its reads queue on the device behind
+                # every step the host has handed over: the fence says
+                # how much of the hold was waiting for those
+                # (publish_queue_drain), the rest is the reads' own.
+                with obs.span("publish_lock_hold") as hold:
+                    fence = obs_device.OBSERVATORY.fence()
+                    version = getattr(agg, "write_version", 0)
+                    for key, ent in entries:
+                        try:
+                            with hold.child("read", key=key):
+                                values[key] = ent[0]()
+                        except Exception:
+                            # one bad closure (e.g. a window that aged
+                            # out) must not abort the epoch or kill the
+                            # ticker
+                            logger.exception(
+                                "mirror publish: compute for %r failed", key
+                            )
+        publish_ms = (epoch.t1 - epoch.t0) * 1000.0
+        if fence is not None:
+            # outside the lock: the clock's thread is at most one wake-up
+            # behind the reads that have just come back
+            drain_s = fence.wait(1.0)
+            if drain_s is not None:
+                # the steps ahead had run before the reads came back,
+                # however late the clock's thread stamped them
+                obs.record("publish_queue_drain",
+                           min(drain_s, hold.t1 - hold.t0))
         new = MirrorSnapshot(
             values=values,
             write_version=version,
@@ -326,7 +345,6 @@ class ReadMirror:
         self.last_publish_ms = publish_ms
         self._publish_done_at = time.monotonic()
         self.publish_ms_sum += publish_ms
-        obs.record("mirror_publish", publish_ms / 1000.0)
         sink = self.segment_sink
         if sink is not None:
             try:

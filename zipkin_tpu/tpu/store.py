@@ -635,13 +635,13 @@ class TpuStorage(
         with self._intern_lock:
             if self._nvocab is None:
                 self._nvocab = native.NativeVocab(self.vocab)
-            t0 = time.perf_counter()
-            self._nvocab.ensure_synced()
-            parsed = native.parse_spans(data, nvocab=self._nvocab)
-            if parsed is None:
-                return None
-            self._nvocab.sync()
-            obs.record("parse", time.perf_counter() - t0)
+            with obs.span("parse") as parse:
+                self._nvocab.ensure_synced()
+                parsed = native.parse_spans(data, nvocab=self._nvocab)
+                if parsed is None:
+                    parse.drop()  # not a payload for this path
+                    return None
+                self._nvocab.sync()
             n = parsed.n
             dropped = 0
             if sampler is not None and sampler.rate < 1.0 and n:
@@ -657,20 +657,24 @@ class TpuStorage(
             if n == 0:
                 return 0, dropped, []
             chunks = []
-            t0 = time.perf_counter()
-            for lo_i in range(0, n, self.max_batch):
-                hi_i = min(lo_i + self.max_batch, n)
-                if lo_i == 0 and hi_i == n:
-                    sub = parsed
-                else:
-                    sub = native.ParsedColumns()
-                    sub.data = parsed.data
-                    for f in _PARSED_FIELDS:
-                        col = getattr(parsed, f, None)
-                        setattr(sub, f, None if col is None else col[lo_i:hi_i])
-                    sub.n = hi_i - lo_i
-                chunks.append((sub, pack_parsed(sub, self.vocab, self._pad)))
-            obs.record("pack", time.perf_counter() - t0)
+            with obs.span("pack"):
+                for lo_i in range(0, n, self.max_batch):
+                    hi_i = min(lo_i + self.max_batch, n)
+                    if lo_i == 0 and hi_i == n:
+                        sub = parsed
+                    else:
+                        sub = native.ParsedColumns()
+                        sub.data = parsed.data
+                        for f in _PARSED_FIELDS:
+                            col = getattr(parsed, f, None)
+                            setattr(
+                                sub, f,
+                                None if col is None else col[lo_i:hi_i],
+                            )
+                        sub.n = hi_i - lo_i
+                    chunks.append(
+                        (sub, pack_parsed(sub, self.vocab, self._pad))
+                    )
         return n, dropped, chunks
 
     def _fast_dispatch(self, parsed, cols) -> None:
@@ -1796,10 +1800,15 @@ class TpuStorage(
             "deviceProgramCalls": _dev_totals["calls"],
             "deviceCompiles": _dev_totals["compiles"],
             "deviceRecompiles": _dev_totals["recompiles"],
+            # its completion clock (process-global too): steps the device
+            # has run, plain against maintenance-fused, their device and
+            # queue time, and how far the host is ahead of the device
+            # (step* / deviceQueue*; all 0 with the observatory off)
+            **OBSERVATORY.queue.counters(),
             # incremental link-ctx gauges (ISSUE 5): lanes the next
             # fresh read must delta-merge (bounded by rollup_segment),
-            # ctx advances run, and the host wall of the last
-            # ctx-advancing (rollup-fused) dispatch
+            # ctx advances run, and the device time of the last
+            # ctx-advancing (rollup-fused) step, from the completion clock
             "ctxDeltaLanes": self.agg._lanes_since_rollup,
             "ctxAdvances": self.agg.ctx_stats["ctx_advances"],
             "ctxMaintenanceMs": self.agg.ctx_stats["ctx_maintenance_ms"],

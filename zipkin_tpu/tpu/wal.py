@@ -98,7 +98,13 @@ class WriteAheadLog:
             # segment via _file_for and log a batch after the final
             # snapshot — double-replay on next boot (r3 review finding)
             raise RuntimeError("WAL is closed")
-        t0 = time.perf_counter()
+        # record write incl. buffer flush and fsync; an append that ran
+        # into a full disk is counted by enospc_count, not here
+        with obs.span("wal_append") as whole:
+            return self._append_record(fused, meta, whole)
+
+    def _append_record(self, fused: np.ndarray, meta: dict, whole) -> int:
+        t0 = whole.t0
         self._seq += 1
         # memoryview, not tobytes(): the image is already contiguous u32
         # (or made so here) and BufferedWriter/crc32 both consume the
@@ -141,16 +147,17 @@ class WriteAheadLog:
                 critpath.SEG_WAL_APPEND, int(t0 * 1e9), int(t1 * 1e9)
             )
             if self.fsync and not deferred:
-                os.fsync(fh.fileno())
-                t2 = time.perf_counter()
-                obs.record("wal_fsync", t2 - t1)
+                with obs.span("wal_fsync") as sync:
+                    os.fsync(fh.fileno())
                 critpath.stamp_active(
-                    critpath.SEG_WAL_FSYNC, int(t1 * 1e9), int(t2 * 1e9)
+                    critpath.SEG_WAL_FSYNC,
+                    int(sync.t0 * 1e9), int(sync.t1 * 1e9),
                 )
         except OSError as e:
             if e.errno != errno.ENOSPC:
                 raise
             self._note_enospc()
+            whole.drop()
             return self._seq
         # bit-rot injection site (ISSUE 7): the record's payload bytes
         # are durable — damage them at rest; the process keeps running
@@ -163,7 +170,6 @@ class WriteAheadLog:
             self._fh_bytes + _HEADER.size + len(meta_b), len(payload),
         )
         self._fh_bytes += rec_len
-        obs.record("wal_append", time.perf_counter() - t0)
         return self._seq
 
     @contextlib.contextmanager
