@@ -10,7 +10,16 @@ Linux is one clock for every process of the machine, so ``run.py`` can
 compare them with its own.
 
 An operation is one batch. Its time runs from when it was first due to its
-202, 429s retried after the server's ``X-Retry-After-Ms`` included. The one
+202, 429s retried after the server's ``X-Retry-After-Ms`` included. A POST
+that the transport fails (a timeout, a reset) is never sent again, since a
+second copy would be spans the reference does not know: it is recorded with
+the exception as its ``status``, counts as failed, and its sender goes on to
+its next batch on a fresh connection. The window's POSTs wait ``OP_TIMEOUT_S``
+for their answer, the set-up's ``SETUP_TIMEOUT_S``: a fresh server compiles
+its read programs one after the other under the lock that a POST needs, for
+minutes (PERF.md section 6). A run that cannot go on ends with one line on
+standard error, ``chipbench-client: <phase>: <reason>``, the same on standard
+output as ``FAILED <json>`` for ``run.py``, and exit code 1. The one
 loop kind so far is ``closed``: ``connections`` senders, each sending its next
 batch on the 202, which finds the pace where the configuration's 202 means
 applied. A workload file that asks for another kind is refused: the PR that
@@ -28,10 +37,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import gen  # noqa: E402
-from launcher import Http  # noqa: E402
+from launcher import TRANSPORT_ERRORS, Http, RunFailure  # noqa: E402
 
 DRAIN_LIMIT_S = 90.0  # an answer that comes late is late, not wrong
-OP_TIMEOUT_S = 120.0
+OP_TIMEOUT_S = 120.0  # a POST of the window: part of what an operation is
+SETUP_TIMEOUT_S = 900.0  # a POST of the set-up: as long as its reads wait
 WARM_ROUNDS = 4
 
 
@@ -50,6 +60,7 @@ class Client:
         self.lock = threading.Lock()
         self.next_n = 0
         self.sends = []  # dicts, one per batch
+        self.phase = "boot"  # then "setup", "window", "drain": run.py's names
 
     # ---- writes ----------------------------------------------------------
 
@@ -69,7 +80,8 @@ class Client:
                 status, text, headers = http.request(
                     "POST", "/api/v2/spans", body,
                     {"Content-Type": "application/json"})
-            except OSError as e:
+            except TRANSPORT_ERRORS as e:
+                # never sent again; ``http`` has closed its connection
                 rec["status"] = f"{type(e).__name__}: {e}"
                 break
             now = time.monotonic()
@@ -88,25 +100,35 @@ class Client:
             self.sends.append(rec)
         return rec
 
-    def closed_loop(self, count: int = None, until: float = None,
-                    give_up_at: float = None, phase: str = "window") -> None:
-        """``connections`` senders, each sending its next batch on the 202.
-        Ends after ``count`` batches in all, or at ``until``."""
+    def closed_loop(self, timeout_s: float, count: int = None,
+                    until: float = None, give_up_at: float = None,
+                    phase: str = "window") -> None:
+        """``connections`` senders, each sending its next batch on the 202 and
+        waiting ``timeout_s`` for it. Ends after ``count`` batches in all, or
+        at ``until``. A sender outlives a batch that failed; one that an
+        exception ends all the same fails the run, since the loop would go on
+        with fewer connections than the cell states."""
         left = [count]
+        ended = []
 
         def worker() -> None:
-            http, scratch = Http(self.port, OP_TIMEOUT_S), {}
-            while True:
-                with self.lock:
-                    if count is not None:
-                        if left[0] <= 0:
-                            break
-                        left[0] -= 1
-                if until is not None and time.monotonic() >= until:
-                    break
-                self.send_batch(http, scratch, self.take_n(), time.monotonic(),
-                                give_up_at or time.monotonic() + 900.0, phase)
-            http.close()
+            http, scratch = Http(self.port, timeout_s), {}
+            try:
+                while True:
+                    with self.lock:
+                        if count is not None:
+                            if left[0] <= 0:
+                                break
+                            left[0] -= 1
+                    if until is not None and time.monotonic() >= until:
+                        break
+                    self.send_batch(
+                        http, scratch, self.take_n(), time.monotonic(),
+                        give_up_at or time.monotonic() + timeout_s, phase)
+            except Exception as e:  # the thread's boundary: reported below
+                ended.append(f"{type(e).__name__}: {e}")
+            finally:
+                http.close()
 
         threads = [threading.Thread(target=worker)
                    for _ in range(int(self.posts["connections"]))]
@@ -114,6 +136,9 @@ class Client:
             t.start()
         for t in threads:
             t.join()
+        if ended:
+            raise RunFailure(f"{len(ended)} of {len(threads)} senders ended "
+                             f"by an exception, the first: {ended[0]}")
 
     # ---- the applied-span counter ------------------------------------------
 
@@ -130,7 +155,7 @@ class Client:
         http.close()
         return float("nan")
 
-    def device_sync(self, t_counted: float) -> float:
+    def device_sync(self, t_counted: float, timeout_s: float) -> float:
         """When the device has run every step fed to it. The applied-span
         counter moves when a step is handed to the device's queue, and the
         host runs seconds ahead of the device (PERF.md section 6), so the
@@ -141,7 +166,7 @@ class Client:
         path = self.cfg.get("device_sync")
         if not path or t_counted != t_counted:
             return t_counted
-        http = Http(self.port, OP_TIMEOUT_S)
+        http = Http(self.port, timeout_s)
         nonce = f"0.9{1000 + (self.spec['seed'] * 7919 + self.next_n) % 9000}"
         http.get_json(path.replace("{nonce}", nonce))
         http.close()
@@ -152,11 +177,13 @@ class Client:
         readers' deltas and the count of compiles inside the window."""
         http = Http(self.port, OP_TIMEOUT_S)
         statusz = http.get_json("/api/v2/tpu/statusz")
+        programs = statusz.get("device", {}).get("programs", {})
         out = {"t": time.monotonic(), "stages": statusz.get("stages", {}),
                "device_totals": statusz.get("device", {}).get("totals", {}),
-               "program_calls": {
-                   n: [p.get("calls", 0), p.get("compiles", 0)] for n, p in
-                   statusz.get("device", {}).get("programs", {}).items()},
+               "program_calls": {n: [p.get("calls", 0), p.get("compiles", 0)]
+                                 for n, p in programs.items()},
+               "program_compile_ms": {n: p.get("compileWallMs", 0.0)
+                                      for n, p in programs.items()},
                "counters": http.get_json("/api/v2/tpu/counters")}
         http.close()
         return out
@@ -172,11 +199,16 @@ class Client:
         connections: what the window can reach, set-up can), one step each,
         then wait until all are applied."""
         if count > 0:
-            self.closed_loop(count=count, phase="fill")
+            self.closed_loop(SETUP_TIMEOUT_S, count=count, phase="fill")
         bad = [s for s in self.sends if s["status"] != 202]
         if bad:
-            raise RuntimeError(f"set-up: {len(bad)} batches refused: {bad[0]}")
-        self.wait_applied(self.next_n * self.post_spans, 900.0)
+            first = {k: bad[0][k] for k in ("n", "status", "retries")}
+            raise RunFailure(f"{len(bad)} batches refused, the first: {first}")
+        want = self.next_n * self.post_spans
+        applied = self.wait_applied(want, SETUP_TIMEOUT_S)
+        if applied != applied:
+            raise RunFailure(f"the applied counter did not reach {want} spans "
+                             f"in {SETUP_TIMEOUT_S:.0f} s")
 
     def since_rollup(self) -> int:
         """Batches since the half-ring was last rolled up, from this client's
@@ -218,7 +250,7 @@ class Client:
         ends for lack of progress, and nothing fails. A configuration for a
         program with one step shape leaves ``warm`` out."""
         warm = self.cfg.get("warm") or {}
-        http = Http(self.port, 900.0)
+        http = Http(self.port, SETUP_TIMEOUT_S)
         self.post_applied(1)
         wanted = warm.get("step_programs") or []
         if not wanted:
@@ -263,7 +295,7 @@ class Client:
         the last flush. Runs that started in different phases settled into
         rates 5-10% apart (PERF.md section 6). -> batches sent."""
         per_roll = self.cfg["agg"]["ring_capacity"] // 2 // self.post_spans
-        http = Http(self.port, 900.0)
+        http = Http(self.port, SETUP_TIMEOUT_S)
         pad = (per_roll // 2 - self.since_rollup()) % per_roll
         self.post_applied(pad)
         flush_by = (self.cfg.get("warm") or {}).get("flush_by")
@@ -284,14 +316,15 @@ class Client:
                 if probe.request("GET", "/health")[0] == 200:
                     probe.close()
                     return
-            except OSError:
-                probe.close()
+            except TRANSPORT_ERRORS:
+                pass
             time.sleep(0.25)
-        raise RuntimeError("/health did not answer")
+        raise RunFailure("/health did not answer")
 
     def run(self) -> dict:
         seconds = float(self.spec["seconds"])
         self.wait_health()
+        self.phase = "setup"
         # set-up: every step variant at the cell's own POST size, then the
         # rest of one ring's worth, so the window runs with eviction
         warm_rounds, warm_missing = self.warm_steps()
@@ -300,17 +333,21 @@ class Client:
         self.pin_phase()
         # the window starts with the device's queue empty, and the read that
         # will close it has run (and compiled) once
-        self.device_sync(time.monotonic())
+        self.device_sync(time.monotonic(), SETUP_TIMEOUT_S)
         before = self.snapshot()
         t0 = time.monotonic()
+        self.phase = "window"
         print(f"WINDOW {t0!r}", flush=True)
         give_up_at = t0 + seconds + DRAIN_LIMIT_S
-        self.closed_loop(until=t0 + seconds, give_up_at=give_up_at)
+        self.closed_loop(OP_TIMEOUT_S, until=t0 + seconds,
+                         give_up_at=give_up_at)
         t_close = time.monotonic()
+        self.phase = "drain"
+        print(f"CLOSED {t_close!r}", flush=True)
         acked = sum(1 for s in self.sends if s["status"] == 202)
         t_drained = self.wait_applied(acked * self.post_spans,
                                       max(1.0, give_up_at - time.monotonic()))
-        t_drained = self.device_sync(t_drained)
+        t_drained = self.device_sync(t_drained, OP_TIMEOUT_S)
         after = self.snapshot()
         print(f"DRAINED {t_drained!r}", flush=True)
         return {
@@ -326,7 +363,17 @@ class Client:
 def main() -> int:
     with open(sys.argv[1]) as f:
         spec = json.load(f)
-    out = Client(spec).run()
+    client = Client(spec)
+    try:
+        out = client.run()
+    except (RunFailure, *TRANSPORT_ERRORS) as e:
+        reason = str(e) if isinstance(e, RunFailure) \
+            else f"{type(e).__name__}: {e}"
+        print("FAILED " + json.dumps(
+            {"failed_in": client.phase, "reason": reason}), flush=True)
+        print(f"chipbench-client: {client.phase}: {reason}", file=sys.stderr,
+              flush=True)
+        return 1
     tmp = spec["out"] + ".tmp"
     with open(tmp, "w") as f:
         json.dump(out, f)

@@ -27,6 +27,13 @@ ROOT = os.path.dirname(HERE)
 WAIT_S = 900.0  # a cold compile on the chip takes minutes
 
 
+# what a request can raise: a timeout and a reset are OSErrors, a connection
+# left half-way through a request or answered with garbage an HTTPException
+TRANSPORT_ERRORS = (OSError, http.client.HTTPException)
+STALE_ERRORS = (http.client.RemoteDisconnected, BrokenPipeError,
+                ConnectionResetError, http.client.CannotSendRequest)
+
+
 class RunFailure(Exception):
     """A phase could not run to its end (transport, timeout, status)."""
 
@@ -46,7 +53,10 @@ class Http:
 
     def request(self, method: str, path: str, body: bytes = None,
                 headers: dict = None):
-        """-> (status, body bytes, response headers dict)."""
+        """-> (status, body bytes, response headers dict). Whatever exception
+        comes out, the connection is closed first: a request that was sent and
+        not answered leaves it unusable (``CannotSendRequest``), and the next
+        request has to start on a fresh one."""
         for attempt in (0, 1):
             if self.conn is None:
                 self.conn = http.client.HTTPConnection(
@@ -56,13 +66,12 @@ class Http:
                 resp = self.conn.getresponse()
                 data = resp.read()
                 return resp.status, data, resp.headers
-            except (http.client.RemoteDisconnected, BrokenPipeError,
-                    ConnectionResetError, http.client.CannotSendRequest):
+            except TRANSPORT_ERRORS as e:
+                self.close()
                 # a keep-alive connection the server closed: a GET goes once
                 # more on a fresh one; a POST may have been taken, and a
                 # second copy would be spans the reference does not know
-                self.close()
-                if attempt or method != "GET":
+                if attempt or method != "GET" or not isinstance(e, STALE_ERRORS):
                     raise
 
     def get_json(self, path: str, **params):
@@ -121,8 +130,8 @@ class Server:
                 if status == 200:
                     probe.close()
                     return time.monotonic() - t0
-            except OSError:
-                probe.close()
+            except TRANSPORT_ERRORS:
+                pass
             time.sleep(0.25)
         raise RunFailure(f"/health did not answer within {WAIT_S:.0f}s")
 

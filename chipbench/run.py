@@ -11,7 +11,9 @@ server's answers with the plain reference (``reference.py``, ``compare.py``) and
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device``, and with ``--trace 1`` ``breakdown``; then ``compared``, each
 number beside its limit. ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics.
+``--trace 1`` its per-layer metrics. A run that fails prints no result, exits
+with code 1, and ends its standard error with one JSON object,
+``{"failed_in": <phase>, "reason": ...}``, the phase one of ``PHASES``.
 
 This process and the client never import JAX: the chip belongs to the server
 child. A run whose server is not on a TPU, or sees another number of chips
@@ -38,6 +40,7 @@ import sys
 import tempfile
 import threading
 import time
+import traceback
 
 T_START = time.monotonic()  # set-up counts from here
 
@@ -49,9 +52,13 @@ import compare as compare_mod  # noqa: E402
 import gen  # noqa: E402
 import measures  # noqa: E402
 import reference  # noqa: E402
-from launcher import RunFailure, Server  # noqa: E402
+from launcher import TRANSPORT_ERRORS, RunFailure, Server  # noqa: E402
 
 RUN_LIMIT_S = 1150  # a cold first run compiles; the driver allows 1200
+# where a run can fail, in order; the client's lines move the middle ones on
+PHASES = ("boot", "setup", "window", "drain", "settle", "fetch", "trace",
+          "compare")
+CLIENT_SAYS = {"WINDOW": "window", "CLOSED": "drain", "DRAINED": "settle"}
 TRACE_SLICE_S = 4.0
 FINAL_NAME_SERVICES = 5  # services whose span names are compared
 
@@ -162,6 +169,23 @@ def layer_value(name: str, ctx: dict):
     return mod.read(ctx, spec.get("params", {}))
 
 
+def setup_waits(at_health: dict, result: dict) -> dict:
+    """What the set-up waited for, for ``notes``: the programs the server
+    compiled between ``/health`` and the window (or read from the compile
+    cache: a hit counts as a compile of some tenths of a second) and the
+    walls of those compiles summed, by the server's own table; and the longest
+    POST of the set-up. A cold run shows as one in its own line."""
+    before = result["before"]
+    compiles = sum(c[1] for c in before["program_calls"].values()) - sum(
+        p.get("compiles", 0) for p in at_health.values())
+    wall_ms = sum(before["program_compile_ms"].values()) - sum(
+        p.get("compileWallMs", 0.0) for p in at_health.values())
+    posts = [s["acked"] - s["due"] for s in result["sends"]
+             if s["phase"] == "fill" and s["acked"] is not None]
+    return {"setup_compiles": compiles, "setup_compile_s": wall_ms / 1000.0,
+            "setup_post_max_s": max(posts, default=None)}
+
+
 def reduce_trace(trace_dir: str) -> dict:
     """The xplane reducer in a process of its own, after the server is gone:
     reading a trace needs JAX's reader, and this process stays off JAX."""
@@ -202,6 +226,7 @@ def main() -> int:
     server = Server(config, workdir, traced, trace_seconds=slice_s)
     client = None
     timer = None
+    phase = "boot"
     try:
         # the client starts at once and builds its templates while the
         # server boots; so does this process, for the reference
@@ -218,6 +243,7 @@ def main() -> int:
         traffic = gen.Traffic(args.seed, config["fleet"], workload["posts"])
         server.wait_health()
         device = read_device(server)
+        programs_at_health = device["programs"]
         on_chip = (device["platform"] == "tpu"
                    and device["count"] == cell["chips"])
         if not on_chip and not args.rehearse:
@@ -226,8 +252,15 @@ def main() -> int:
                   file=sys.stderr)
             return 3
         setup_s = None
+        phase = "setup"
+        client_failure = None
         for line in client.stdout:
-            if line.startswith("WINDOW "):
+            word = line.split(" ", 1)[0]
+            phase = CLIENT_SAYS.get(word, phase)
+            if word == "FAILED":
+                client_failure = json.loads(line.split(" ", 1)[1])
+                phase = client_failure["failed_in"]
+            if word == "WINDOW":
                 t0 = float(line.split()[1])
                 setup_s = t0 - T_START
                 if traced:
@@ -239,13 +272,17 @@ def main() -> int:
             if not server.alive():
                 raise RunFailure("the server died during the run")
         if client.wait() != 0 or setup_s is None:
-            raise RunFailure(f"the client exited with {client.returncode}")
+            raise RunFailure(
+                f"the client exited with {client.returncode}: "
+                + (client_failure or {}).get("reason", "it did not say why"))
         result = load_json(spec["out"])
 
         device = read_device(server)  # the peak, before anything else runs
         settle_s = settle(server, config, result)
+        phase = "fetch"
         final = fetch_final(server, config, args.seed)
         xplane = None
+        phase = "trace"
         if traced:
             done = os.path.join(workdir, "trace", "done.json")
             t_wait = time.monotonic()
@@ -258,6 +295,7 @@ def main() -> int:
             xplane = reduce_trace(os.path.join(workdir, "trace"))
 
         # the reference, once the window has closed and the server is gone
+        phase = "compare"
         ref = reference.Reference(traffic.templates)
         detail = {}
         numbers = compare_mod.compare(
@@ -294,7 +332,8 @@ def main() -> int:
             line["breakdown"] = {"device_ops": xplane["device_ops"][:10],
                                  "idle_gaps": xplane["idle_gaps"][:10]}
         line["notes"] = dict(ops, e2e=e2e, settle_s=settle_s,
-                             compare_detail=detail)
+                             compare_detail=detail,
+                             **setup_waits(programs_at_health, result))
         if args.control:
             import control
             line["notes"]["control"] = control.readings(
@@ -311,10 +350,16 @@ def main() -> int:
             return 3
         print(json.dumps(line), flush=True)
         return 0
-    except (RunFailure, OSError, subprocess.TimeoutExpired) as e:
+    except Exception as e:  # the run's boundary: said last, on one line
+        if not isinstance(e, (RunFailure, subprocess.TimeoutExpired,
+                              *TRANSPORT_ERRORS)):
+            traceback.print_exc()
         print(f"chipbench: {type(e).__name__}: {e}", file=sys.stderr)
         print("---- server stderr (tail) ----", file=sys.stderr)
         print(server.stderr_tail(), file=sys.stderr)
+        print(json.dumps({"failed_in": phase,
+                          "reason": f"{type(e).__name__}: {e}"}),
+              file=sys.stderr, flush=True)
         return 1
     finally:
         signal.alarm(0)

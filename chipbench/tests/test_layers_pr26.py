@@ -48,10 +48,12 @@ def test_a_program_without_the_clock_reads_nothing_and_does_not_raise():
 
 
 def test_the_rehearsal_line_carries_all_eight():
+    # 12 s: the publish pair reads nothing in a window that holds no publish
+    # of the read mirror, and a 2 s window held one about every second time
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "2147483801", "--seconds", "2", "--trace", "1", "--rehearse"],
+         "2147483801", "--seconds", "12", "--trace", "1", "--rehearse"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
     assert p.returncode == 3 and p.stdout.strip() == ""
     marker = "It would have been: "
