@@ -22,14 +22,16 @@ from __future__ import annotations
 import functools
 import json
 import random
+from typing import NamedTuple
 
 import jax
 import numpy as np
 import pytest
 
-from tests.fixtures import lots_of_spans
+from tests.fixtures import TODAY_US, lots_of_spans
 from zipkin_tpu import faults
-from zipkin_tpu.ops import linker
+from zipkin_tpu.model.span import Endpoint, Kind, Span
+from zipkin_tpu.ops import delta_linker, linker
 from zipkin_tpu.storage.tpu import TpuStorage
 from zipkin_tpu.tpu import ingest as ing
 from zipkin_tpu.tpu.columnar import Vocab, pack_spans
@@ -123,6 +125,297 @@ def test_empty_ring_and_first_batches():
         sub = type(cols)(*(np.asarray(f[lo:lo + 16]) for f in cols))
         state = step(state, sub)
         assert_ctx_identical(cfg, state, "before the first rollup")
+
+
+# ----------------------------------------------------------------------
+# the advance itself: one sort of the union serves tree and ctx (ISSUE 33)
+# ----------------------------------------------------------------------
+
+
+def tangled_spans(n, seed):
+    """Chains of RPCs with what makes the joins hard: shared server
+    halves with their client mate and without one (a mateless half must
+    fall back to its parentId), span ids reported twice from different
+    services, and a span that names itself as parent."""
+    rng = random.Random(seed)
+    svc = [Endpoint.create(f"svc{i:02d}", f"10.0.0.{i + 1}") for i in range(8)]
+    spans = []
+    trace = 0
+    while len(spans) < n:
+        trace += 1
+        tid = f"{rng.getrandbits(63) | 1:016x}"
+        parent, caller = None, rng.randrange(8)
+        for level in range(rng.randint(1, 5)):
+            sid = f"{(trace << 8 | level) + 1:016x}"
+            callee = rng.randrange(8)
+            common = dict(
+                name=f"op{rng.randrange(6)}", timestamp=TODAY_US + trace * 1000 + level,
+                duration=50 + rng.randrange(1000),
+            )
+            client = Span.create(
+                tid, sid, parent_id=parent, kind=Kind.CLIENT,
+                local_endpoint=svc[caller], remote_endpoint=svc[callee], **common,
+            )
+            server = Span.create(
+                tid, sid, parent_id=parent, kind=Kind.SERVER, shared=True,
+                local_endpoint=svc[callee], **common,
+            )
+            roll = rng.random()
+            if roll < 0.45:
+                spans += [client, server]       # shared half with its mate
+            elif roll < 0.65:
+                spans.append(server)            # shared half, no mate
+            else:
+                spans.append(client)
+            if rng.random() < 0.15:             # the same id reported twice
+                spans.append(Span.create(
+                    tid, sid, parent_id=parent, kind=Kind.CLIENT,
+                    local_endpoint=svc[rng.randrange(8)], **common,
+                ))
+            if rng.random() < 0.03:             # its own parent
+                spans.append(Span.create(
+                    tid, f"{(trace << 8 | 0x80 | level) + 1:016x}",
+                    parent_id=f"{(trace << 8 | 0x80 | level) + 1:016x}",
+                    kind=Kind.CLIENT, local_endpoint=svc[caller], **common,
+                ))
+            parent, caller = sid, callee
+    return spans[:n]
+
+
+class Advance(NamedTuple):
+    """One roll-up of a trajectory, as numpy: what ``advance`` returned
+    (twice, on the same input), what the from-scratch oracle says of the
+    same ring, and the ctx leaves ``rollup_step`` left in the state."""
+
+    since: int        # lanes written since the advance before
+    got: tuple        # new ctx, parent, anc, root_ok, LinkContext
+    again: tuple      # the same call a second time
+    oracle: tuple     # parent, has_child, anc, root_ok, LinkContext
+    state_ctx: tuple  # CtxStruct leaves of the state after rollup_step
+
+
+@pytest.fixture(scope="module", params=[(21, 7), (22, 8), (23, 9)],
+                ids=lambda p: f"seed{p[0]}-ring{1 << p[1]}")
+def trajectory(request):
+    """Four ring wraps of tangled spans in small batches, rolled up at
+    the host cadence and twice on a part-written segment, with a fresh
+    read compared after every batch. The first advances see a ring that
+    still has never-written (invalid) lanes."""
+    seed, ring_pow = request.param
+    cfg = AggConfig(
+        max_services=64, max_keys=256, hll_precision=9,
+        digest_centroids=32, ring_capacity=1 << ring_pow,
+    )
+    seg = cfg.rollup_segment
+    cols = pack_spans(
+        tangled_spans(4 << ring_pow, seed),
+        Vocab(max_services=64, max_keys=256), pad_to_multiple=8,
+    )
+    step = jax.jit(lambda s, b: ing.ingest_step(cfg, s, b))
+    rollup = jax.jit(lambda s: ing.rollup_step(cfg, s))
+    fresh, oracle_ctx = _ctx_programs(cfg)
+
+    advance = jax.jit(lambda s: delta_linker.advance(
+        ing.ring_link_input(s), ing.ctx_struct(s), seg
+    ))
+
+    # a program of its own: inside one jit XLA would merge the oracle's
+    # sort with the advance's and compare a value with itself
+    @jax.jit
+    def from_scratch(s):
+        x = ing.ring_link_input(s)
+        parent, child = linker.resolve_parents(x)
+        anc, root_ok = linker.chase_ancestors(
+            parent, jax.numpy.where(x.valid, x.kind, 0)
+        )
+        ctx = linker.apply_rules(x, parent, child, anc, root_ok)
+        return parent, child, anc, root_ok, ctx
+
+    def host(tree):
+        return jax.tree_util.tree_map(np.asarray, tree)
+
+    state = init_state(cfg)
+    rnd = random.Random(seed)
+    advances, reads = [], []
+    lo = since = 0
+    while lo < cols.size:
+        sz = rnd.choice([8, 8, 16, 24])
+        sub = type(cols)(*(np.asarray(f[lo:lo + sz]) for f in cols))
+        lo += sz
+        lanes = int(sub.valid.sum())
+        # a roll-up where the host cadence asks for one, and every third
+        # time on a segment only part-written
+        early = len(advances) % 3 == 1 and since >= seg // 4
+        if since + lanes > seg or early:
+            got, want = advance(state), from_scratch(state)
+            again = advance(state)
+            state = rollup(state)
+            advances.append(Advance(
+                since, host(got), host(again), host(want),
+                host(ing.ctx_struct(state)),
+            ))
+            since = 0
+        state = step(state, sub)
+        since += lanes
+        reads.append((lo, since, host(fresh(state)), host(oracle_ctx(state))))
+    return cfg, advances, reads
+
+
+def test_advance_trajectory_is_long_enough(trajectory):
+    cfg, advances, reads = trajectory
+    assert len(advances) >= 8  # four wraps, two roll-ups a wrap
+    assert any(0 < a.since < cfg.rollup_segment - 24 for a in advances)
+    assert max(since for _, since, _, _ in reads) == cfg.rollup_segment
+
+
+def test_advance_tree_is_the_oracles(trajectory):
+    """parent, anc, root_ok and the emit context (which carries
+    has_child through rule 1) equal resolve_parents / chase_ancestors /
+    apply_rules on the same ring, bit for bit, at every roll-up."""
+    _, advances, _ = trajectory
+    for k, a in enumerate(advances):
+        _, parent, anc, root_ok, ctx = a.got
+        o_parent, _o_child, o_anc, o_root, o_ctx = a.oracle
+        for name, g, w in [("parent", parent, o_parent), ("anc", anc, o_anc),
+                           ("root_ok", root_ok, o_root)]:
+            np.testing.assert_array_equal(g, w, err_msg=f"{name}, roll-up {k}")
+        for name, g, w in zip(ctx._fields, ctx, o_ctx):
+            np.testing.assert_array_equal(
+                g, w, err_msg=f"LinkContext.{name}, roll-up {k}")
+
+
+def test_advance_has_child_is_the_oracles():
+    """has_child leaves advance only inside the emit context, so it is
+    held directly here, through the two halves advance is made of."""
+    cfg = AggConfig(
+        max_services=64, max_keys=256, hll_precision=9,
+        digest_centroids=32, ring_capacity=1 << 7,
+    )
+    cols = pack_spans(
+        tangled_spans(3 << 7, 5), Vocab(max_services=64, max_keys=256),
+        pad_to_multiple=8,
+    )
+    step = jax.jit(lambda s, b: ing.ingest_step(cfg, s, b))
+
+    @jax.jit
+    def halves(s):
+        x = ing.ring_link_input(s)
+        su = linker.sort_union(x)
+        mins = linker._run_min_ladder(linker.tree_channels(su), 2 << 7)
+        return linker.choose_parents(x, su, *mins), linker.resolve_parents(x)
+
+    state = init_state(cfg)
+    for lo in range(0, cols.size, 32):
+        state = step(state, type(cols)(*(np.asarray(f[lo:lo + 32]) for f in cols)))
+        (parent, child), (o_parent, o_child) = halves(state)
+        np.testing.assert_array_equal(np.asarray(parent), np.asarray(o_parent))
+        np.testing.assert_array_equal(np.asarray(child), np.asarray(o_child))
+        assert np.asarray(child).any()
+
+
+def test_advance_ctx_is_a_sorted_union(trajectory):
+    """keys lexicographically non-decreasing, equal keys in union-index
+    order (the tie rule that makes the leaves reproducible), inv the
+    inverse of order, run ids non-decreasing from 1 and stepping exactly
+    where the keys change, safe candidates only from lanes the cursor
+    cannot reach before the next advance."""
+    cfg, advances, _ = trajectory
+    n, seg = cfg.ring_capacity, cfg.rollup_segment
+    for k, a in enumerate(advances):
+        cs = a.got[0]
+        keys = [cs.keys[i].astype(np.int64) for i in range(4)]
+        packed = [tuple(int(kk[j]) for kk in keys) for j in range(2 * n)]
+        assert packed == sorted(packed), f"keys unsorted, roll-up {k}"
+        same = np.array([packed[j] == packed[j - 1] for j in range(1, 2 * n)])
+        assert (np.diff(cs.order)[same] > 0).all(), f"tie order, roll-up {k}"
+        np.testing.assert_array_equal(cs.inv[cs.order], np.arange(2 * n))
+        np.testing.assert_array_equal(np.sort(cs.order), np.arange(2 * n))
+        same3 = np.array([packed[j][:3] == packed[j - 1][:3]
+                          for j in range(1, 2 * n)])
+        for rid, eq in ((cs.rid_c, same3), (cs.rid_f, same)):
+            assert rid[0] == 1
+            np.testing.assert_array_equal(np.diff(rid), (~eq).astype(np.int32))
+        for safe in (cs.safe_sh, cs.safe_ns, cs.safe_fsh):
+            has = safe >= 0
+            assert ((safe[has] - cs.pos) % n >= seg).all()
+        assert cs.delta == 0
+
+
+def test_advance_is_deterministic_and_is_what_rollup_step_stores(trajectory):
+    _, advances, _ = trajectory
+    for k, a in enumerate(advances):
+        for name, g, again, stored in zip(
+            a.got[0]._fields, a.got[0], a.again[0], a.state_ctx
+        ):
+            np.testing.assert_array_equal(
+                g, again, err_msg=f"ctx.{name} differs on a second run, roll-up {k}")
+            np.testing.assert_array_equal(
+                g, stored, err_msg=f"ctx.{name} is not the state's, roll-up {k}")
+
+
+def test_fresh_reads_after_each_advance_are_the_oracles(trajectory):
+    """After any number of further writes up to rollup_segment."""
+    _, _, reads = trajectory
+    for lo, since, got, want in reads:
+        for name, g, w in zip(got._fields, got, want):
+            np.testing.assert_array_equal(
+                g, w, err_msg=f"LinkContext.{name} at span offset {lo}, "
+                f"{since} lanes after the advance")
+
+
+# ----------------------------------------------------------------------
+# structural fence: what would otherwise show only on the chip
+# ----------------------------------------------------------------------
+
+
+def _primitives(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, eqn))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+FENCE_CFG = AggConfig(
+    max_services=64, max_keys=256, hll_precision=9,
+    digest_centroids=32, ring_capacity=1 << 8,
+)
+
+
+def test_rollup_step_has_one_sort_and_one_loop():
+    """ONE sort: the tree and the next ctx come from the same sorted
+    operands. ONE loop: chase_ancestors' convergence-bounded while. A
+    ``fori_loop`` with static bounds traces as ``scan``, so a binary
+    search creeping back into the advance shows as a scan here and as a
+    1-2 s ``while`` on the chip (PERF.md section 6, PR 33)."""
+    prims = _primitives(
+        jax.make_jaxpr(lambda s: ing.rollup_step(FENCE_CFG, s))(
+            init_state(FENCE_CFG)
+        ).jaxpr, [],
+    )
+    names = [name for name, _ in prims]
+    assert names.count("sort") == 1
+    (sort,) = [e for name, e in prims if name == "sort"]
+    assert sort.invars[0].aval.shape == (2 * FENCE_CFG.ring_capacity,)
+    assert sort.params["is_stable"] and sort.params["num_keys"] == 4
+    assert names.count("while") == 1 and names.count("scan") == 0
+
+
+def test_fresh_read_sorts_only_its_delta():
+    prims = _primitives(
+        jax.make_jaxpr(lambda s: ing.fresh_link_context(FENCE_CFG, s))(
+            init_state(FENCE_CFG)
+        ).jaxpr, [],
+    )
+    widths = [e.invars[0].aval.shape[0] for name, e in prims if name == "sort"]
+    assert widths and max(widths) <= 2 * FENCE_CFG.rollup_segment
+    names = [name for name, _ in prims]
+    # the two searches of the stored keys, and the chase
+    assert names.count("scan") == 2 and names.count("while") == 1
 
 
 # ----------------------------------------------------------------------

@@ -1,19 +1,21 @@
 """Incremental link context: the since-rollup delta formulation.
 
 The from-scratch resolve in :mod:`zipkin_tpu.ops.linker` sorts the full
-2n-lane join union on every fresh read (~29.6 ms of the 41.3 ms fresh
-dependency read at ring 2^18, PROFILE_r05). But the rollup cadence
-already bounds how much the ring can change between rollups: the host
-triggers a rollup before writes since the last one exceed
-``rollup_segment`` (R/2), so at any instant the ring differs from its
-state at the last rollup by at most one delta segment. This module
+2n-lane join union (~29.6 ms of the 41.3 ms fresh dependency read at
+ring 2^18, PROFILE_r05). A fresh read should not pay that, and the
+rollup cadence already bounds how much the ring can change between
+rollups: the host triggers a rollup before writes since the last one
+exceed ``rollup_segment`` (R/2), so at any instant the ring differs from
+its state at the last rollup by at most one delta segment. This module
 exploits that bound:
 
 - At each rollup the device ADVANCES a persistent ctx structure: the
   sorted union order, its run decomposition, and per-run first-wins
   candidates restricted to lanes that cannot die before the next
-  advance ("safe" lanes). The advance merges the delta segment into the
-  stored order with binary-searched ranks — no full-ring sort.
+  advance ("safe" lanes). The advance REBUILDS the order with the one
+  full-union sort the rollup's own resolve needs anyway (the delta is
+  half the ring, so merging it into the stored order by searched ranks
+  cost seventy sorts' worth of single-element gathers and saved none).
 - A fresh read sorts ONLY the 2·rollup_segment delta union, binary
   searches the stored (immutable) keys to map delta runs onto stored
   runs, and resolves every candidate by a three-way age-partition
@@ -30,10 +32,11 @@ else the stored safe candidate (immutable between advances), else the
 first delta candidate (from the delta sort). No fallback path, no
 approximation — parity is fuzzed in tests/test_incremental_ctx.py.
 
-Everything here is width-Δ or width-log(n): the only full-width ops are
-elementwise gathers/scatters and the ancestor chase (pointer doubling
-is already convergence-bounded and cheap). ZT-lint rule ZT07 enforces
-that no full-ring sort/scan creeps back into this read path.
+On the READ path everything is width-Δ or width-log(n): the only
+full-width ops are elementwise gathers/scatters and the ancestor chase
+(pointer doubling is already convergence-bounded and cheap). ZT-lint
+rule ZT07 enforces that no full-ring sort/scan creeps back into it; the
+full-ring sort lives at rollup cadence alone.
 """
 
 from __future__ import annotations
@@ -105,12 +108,12 @@ def _lex_eq(a, b):
     return eq
 
 
-def _lower_bound(tbl, q, strict=False):
+def _lower_bound(tbl, q):
     """Vectorized binary search: for each query key (parallel lanes in
-    ``q``) the leftmost index i in [0, len] with tbl[i] >= q (or > q when
-    ``strict``). ``tbl`` lanes must be lex-sorted. ceil(log2(len))+1
-    fixed passes of 4-wide gathers — the price of mapping a delta run
-    onto the stored run universe without touching the full ring."""
+    ``q``) the leftmost index i in [0, len] with tbl[i] >= q. ``tbl``
+    lanes must be lex-sorted. ceil(log2(len))+1 fixed passes of 4-wide
+    gathers — the price of mapping a delta run onto the stored run
+    universe without touching the full ring."""
     size = int(tbl[0].shape[0])
     m = q[0].shape[0]
     lo = jnp.zeros((m,), jnp.int32)
@@ -121,10 +124,7 @@ def _lower_bound(tbl, q, strict=False):
         mid = (lo + hi) >> 1
         mi = jnp.clip(mid, 0, size - 1)
         t = [lane[mi] for lane in tbl]
-        if strict:
-            go_right = ~_lex_lt(q, t)  # tbl[mid] <= q
-        else:
-            go_right = _lex_lt(t, q)  # tbl[mid] < q
+        go_right = _lex_lt(t, q)  # tbl[mid] < q
         act = lo < hi
         lo = jnp.where(act & go_right, mid + 1, lo)
         hi = jnp.where(act & ~go_right, mid, hi)
@@ -135,9 +135,8 @@ def _lower_bound(tbl, q, strict=False):
 
 
 def _resolve_core(x: linker.LinkInput, cs: CtxStruct, seg: int):
-    """Shared delta machinery: everything both the fresh read and the
-    advance need. Returns the resolved tree plus the sorted-delta
-    internals the advance's merge reuses."""
+    """The fresh read's resolve: (parent, has_child) from the stored ctx
+    plus the since-advance delta, paying only for the delta."""
     n = x.valid.shape[0]
     u = 2 * n
     apos = cs.pos
@@ -298,11 +297,7 @@ def _resolve_core(x: linker.LinkInput, cs: CtxStruct, seg: int):
         .max(jnp.where(parent >= 0, 1, 0))
     ).astype(bool)
 
-    return dict(
-        parent=parent, has_child=has_child,
-        dkeys=dkeys, s_isq=s_isq, s_live=s_live, slane=slane,
-        o_alive=o_alive, apos=apos, delta=delta,
-    )
+    return parent, has_child
 
 
 def delta_resolve(
@@ -310,31 +305,31 @@ def delta_resolve(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(parent, has_child) — bit-identical to linker.resolve_parents over
     the same ring, paying only the since-advance delta."""
-    core = _resolve_core(x, cs, seg)
-    return core["parent"], core["has_child"]
+    return _resolve_core(x, cs, seg)
 
 
 def delta_link_context(
     x: linker.LinkInput, cs: CtxStruct, seg: int
 ) -> linker.LinkContext:
     """The fresh-read link context via the delta formulation."""
-    core = _resolve_core(x, cs, seg)
+    parent, has_child = _resolve_core(x, cs, seg)
     anc, root_ok = linker.chase_ancestors(
-        core["parent"], jnp.where(x.valid, x.kind, 0)
+        parent, jnp.where(x.valid, x.kind, 0)
     )
-    return linker.apply_rules(
-        x, core["parent"], core["has_child"], anc, root_ok
-    )
+    return linker.apply_rules(x, parent, has_child, anc, root_ok)
 
 
 def advance(x: linker.LinkInput, cs: CtxStruct, seg: int):
-    """Advance the persistent ctx over the since-last-advance delta.
+    """Advance the persistent ctx to the ring as it stands.
 
-    Runs at rollup cadence (fused into rollup_step): resolves the
-    current tree through the same delta core a read uses, then MERGES
-    the delta entries into the stored sorted order — binary-searched
-    merge ranks plus an alive-compaction, never a full-ring sort — and
-    rebuilds run ids + safe candidates for the NEXT doom window.
+    Runs at rollup cadence (fused into rollup_step). ONE sort of the
+    whole 2n-lane union (:func:`linker.sort_union`, stable, so equal
+    keys land in union-index order on every run) serves both halves:
+    the rollup's tree, chosen exactly as ``linker.resolve_parents``
+    chooses it, and the next ctx — the sorted lanes ARE ``order`` and
+    ``keys``, the run ids come with them, and the safe candidates for
+    the NEXT doom window ride the same run-min ladder as the tree's
+    three channels. Of the old ctx only ``pos`` and ``delta`` are read.
 
     Returns (new_ctx, ctx_parent, ctx_anc, ctx_root, link_context): the
     resolved tree doubles as the rollup's emit context, so the rollup
@@ -342,75 +337,38 @@ def advance(x: linker.LinkInput, cs: CtxStruct, seg: int):
     """
     n = x.valid.shape[0]
     u = 2 * n
-    core = _resolve_core(x, cs, seg)
-    parent, has_child = core["parent"], core["has_child"]
-    apos, delta = core["apos"], core["delta"]
-    npos = (apos + delta) % n
+    # host invariant (ShardedAggregator ingest cadence): at most one
+    # rollup segment is ever written between advances
+    npos = (cs.pos + jnp.clip(cs.delta, 0, seg)) % n
+    su = linker.sort_union(x)
 
-    # ---- stable merge of delta entries into the surviving order -------
-    alive = core["o_alive"]
-    placed = core["s_live"]
-    ac = jnp.cumsum(alive.astype(jnp.int32))
-    ac_pad = jnp.concatenate([jnp.zeros((1,), jnp.int32), ac])
-    pc = jnp.cumsum(placed.astype(jnp.int32))
-    pc_pad = jnp.concatenate([jnp.zeros((1,), jnp.int32), pc])
+    # a table candidate is safe when its age at this advance is >= seg:
+    # the cursor cannot reach it before the next one. su.sh / su.ns are
+    # below the sentinel exactly on valid (shared / non-shared) table
+    # lanes, so the guards need no gather
+    age = (jnp.where(su.order < n, su.order, su.order - n) - npos) % n
 
-    skeys = [cs.keys[0], cs.keys[1], cs.keys[2], cs.keys[3]]
-    dkeys = core["dkeys"]
-    # equal keys tie old-before-delta on both sides of the merge: the
-    # relative order of equal-key entries inside a run is irrelevant to
-    # run identity, it only has to be consistent
-    lbd = _lower_bound(dkeys, skeys)             # delta strictly below old
-    pos_old = (ac - 1) + pc_pad[lbd]
-    lbo = _lower_bound(skeys, dkeys, strict=True)  # old at-or-below delta
-    pos_delta = ac_pad[lbo] + (pc - placed.astype(jnp.int32))
+    def safe(v):
+        return jnp.where((v < u) & (age >= seg), age, u)
 
-    d_union_idx = jnp.where(
-        core["s_isq"], n + core["slane"], core["slane"]
+    safe_sh, safe_ns = safe(su.sh), safe(su.ns)
+    mins = linker._run_min_ladder(
+        linker.tree_channels(su)
+        + [(safe_sh, su.rid_c), (safe_ns, su.rid_c), (safe_sh, su.rid_f)],
+        u,
     )
-    new_order = jnp.zeros((u,), jnp.int32)
-    new_order = new_order.at[jnp.where(alive, pos_old, u)].set(
-        cs.order, mode="drop"
+    parent, has_child = linker.choose_parents(x, su, *mins[:3])
+    nsafe_sh, nsafe_ns, nsafe_fsh = (
+        jnp.where(v >= 0, (npos + v) % n, -1) for v in mins[3:]
     )
-    new_order = new_order.at[jnp.where(placed, pos_delta, u)].set(
-        d_union_idx, mode="drop"
-    )
-
-    # ---- rebuild keys / runs / inverse from the CURRENT ring ----------
-    f_id, f_svc, _ = linker.union_key_lanes(x)
-    nk = [f_id[0][new_order], f_id[1][new_order], f_id[2][new_order],
-          f_svc[new_order]]
-    ncoarse = linker._run_starts(nk[:3])
-    nfine = ncoarse | jnp.asarray(segment_starts(nk[3]))
-    nrid_c = jnp.cumsum(ncoarse.astype(jnp.int32))
-    nrid_f = jnp.cumsum(nfine.astype(jnp.int32))
-    ninv = jnp.zeros((u,), jnp.int32).at[new_order].set(
-        jnp.arange(u, dtype=jnp.int32)
-    )
-
-    # ---- safe candidates for the NEXT doom window ---------------------
-    n_lane = jnp.where(new_order < n, new_order, new_order - n)
-    n_isq = new_order >= n
-    n_age = (n_lane - npos) % n
-    n_tbl_valid = ~n_isq & x.valid[n_lane]
-    n_sh = x.shared[n_lane]
-    bign = jnp.int32(n)
-
-    def smin(guard, rid):
-        tbl = jnp.full((u + 1,), bign, jnp.int32).at[rid].min(
-            jnp.where(guard & (n_age >= seg), n_age, bign)
-        )
-        v = tbl[rid]
-        return jnp.where(v < bign, (npos + v) % n, -1)
-
-    nsafe_sh = smin(n_tbl_valid & n_sh, nrid_c)
-    nsafe_ns = smin(n_tbl_valid & ~n_sh, nrid_c)
-    nsafe_fsh = smin(n_tbl_valid & n_sh, nrid_f)
 
     new_cs = CtxStruct(
-        order=new_order,
-        keys=jnp.stack(nk),
-        rid_c=nrid_c, rid_f=nrid_f, inv=ninv,
+        order=su.order,
+        keys=jnp.stack(su.keys),
+        rid_c=su.rid_c, rid_f=su.rid_f,
+        inv=jnp.zeros((u,), jnp.int32).at[su.order].set(
+            jnp.arange(u, dtype=jnp.int32)
+        ),
         safe_sh=nsafe_sh, safe_ns=nsafe_ns, safe_fsh=nsafe_fsh,
         pos=npos.astype(jnp.int32),
         delta=jnp.zeros((), jnp.int32),

@@ -205,72 +205,102 @@ def _run_min_bcast(vals, starts, none):
     return jnp.where(out >= none, -1, out)
 
 
-def resolve_parents(x: LinkInput) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Tree edges from id joins: returns (parent_row [n] with -1 for roots,
-    has_child [n] bool).
+class SortedUnion(NamedTuple):
+    """The 2n-lane join union after its ONE sort: everything downstream
+    (run ids, first-wins candidates, the preference chain, and the
+    persistent ctx that :func:`zipkin_tpu.ops.delta_linker.advance`
+    keeps for the fresh reads) is contiguous work over these lanes."""
 
-    All three id joins (shared half -> client half, parent-id -> shared
+    keys: Tuple[jnp.ndarray, ...]  # 4 x u32 [2n]: trace_h, id0, id1, svc
+    sh: jnp.ndarray     # i32 [2n] insertion rank of a shared table lane, else 2n
+    ns: jnp.ndarray     # i32 [2n] ... of a non-shared table lane, else 2n
+    qsh: jnp.ndarray    # bool [2n] query lane of a shared span
+    order: jnp.ndarray  # i32 [2n] union index at each sorted position
+    rid_c: jnp.ndarray  # i32 [2n] coarse (trace, id) run id, 1-based
+    rid_f: jnp.ndarray  # i32 [2n] fine (trace, id, svc) run id, 1-based
+
+
+# payload lane of the union sort: the union index below, the span's
+# (valid & shared) and (valid & ~shared) flags above it
+_SH_BIT, _NS_BIT = 30, 29
+
+
+def sort_union(x: LinkInput) -> SortedUnion:
+    """All three id joins (shared half -> client half, parent-id -> shared
     rendition, parent-id -> non-shared) ride ONE multi-operand
     ``lax.sort`` of a 2n-lane union — table lanes keyed by own
     (trace, span-id), query lanes keyed by (trace, parent-id) — that
-    CARRIES the candidate values and selection flags through the sort.
-    Everything after the sort is contiguous: run boundaries are
-    adjacent-lane compares, per-run first-wins candidates are segmented
-    min scans, and the SpanNode._choose_parent preference chain is
-    evaluated in sorted space so only ONE combined candidate needs
-    un-permuting.
-
-    That shape is the r4 redesign of the fresh dependency read
-    (VERDICT r3 order 1): the r3 formulation un-permuted three
-    candidate arrays through gather/scatter passes and fixed-schedule
-    pointer chases, costing 145.8 ms captured device time at ring
-    capacity 2^18; this one measures 23.6 ms for the resolve and
-    34.3 ms for the full link context (chip A/B, bit-identical output).
-    """
+    CARRIES the union index and the selection flags through the sort in
+    one packed payload lane: the TPU compiler's time for a sort grows
+    with its operands (2^19 lanes: 231 s with five, 277 s with six on
+    the sandbox's CPUs, PERF.md section 6, PR 33) and the sort is most
+    of what a roll-up program takes to compile. The candidate values
+    (insertion ranks) follow by one gather. The sort is stable, so equal
+    keys keep union-index order: the same ring always sorts to the same
+    lanes (snapshots and WAL replay compare them)."""
     n = x.valid.shape[0]
-    has_parent = ((x.p0 | x.p1) != 0) & x.valid
-    nonshared = x.valid & ~x.shared
-    sharedv = x.valid & x.shared
-    # ALL spans with parents query the parent-id join — including shared
-    # halves: a shared server span prefers its same-id client half, but
-    # when that mate is absent it must fall back to its parentId exactly
-    # like SpanNode.Builder does (found by the linker fuzz: a mateless
-    # shared span previously became a root and re-attributed its edge)
-    q_valid = has_parent
-
+    if 2 * n > 1 << _NS_BIT:
+        raise ValueError(
+            f"a union of {2 * n} lanes outgrows the payload's index bits"
+        )
     id_lanes, svc_lane, _ = union_key_lanes(x)
+    flags = ((x.valid & x.shared).astype(jnp.int32) << _SH_BIT) | (
+        (x.valid & ~x.shared).astype(jnp.int32) << _NS_BIT
+    )
+    payload = jnp.arange(2 * n, dtype=jnp.int32) | jnp.concatenate(
+        [flags, flags]
+    )
 
+    # zt-lint: disable=ZT07 — fresh entrypoints reach this only through dependency_links' ctx=None fallback, which they never take (they always pass the delta ctx from fresh_link_context); the full-ring sort runs at rollup cadence / cold rebuilds only
+    *keys, pay = jax.lax.sort(
+        tuple(id_lanes) + (svc_lane, payload), num_keys=4
+    )
+    sord = pay & ((1 << _NS_BIT) - 1)
+    is_sh = (pay & (1 << _SH_BIT)) != 0
+    is_ns = (pay & (1 << _NS_BIT)) != 0
+    is_table = sord < n
+    lane = jnp.where(is_table, sord, sord - n)
+    seq_s = lane if x.seq is None else x.seq.astype(jnp.int32)[lane]
+    sent = 2 * n  # run-min "absent" sentinel
+
+    coarse = _run_starts(keys[:3])
+    fine = coarse | jnp.asarray(segment_starts(keys[3]))
+    return SortedUnion(
+        keys=tuple(keys),
+        sh=jnp.where(is_table & is_sh, seq_s, sent),
+        ns=jnp.where(is_table & is_ns, seq_s, sent),
+        # the query half carries the span's shared flag so the
+        # sorted-space selection can pick fallback-vs-preference without
+        # a second unsort
+        qsh=~is_table & is_sh,
+        order=sord,
+        rid_c=jnp.cumsum(coarse.astype(jnp.int32)),
+        rid_f=jnp.cumsum(fine.astype(jnp.int32)),
+    )
+
+
+def tree_channels(su: SortedUnion):
+    """The three run-min channels of the parent choice, in the order
+    :func:`choose_parents` takes their broadcasts: any shared / first
+    non-shared / shared with the same service."""
+    return [(su.sh, su.rid_c), (su.ns, su.rid_c), (su.sh, su.rid_f)]
+
+
+def choose_parents(
+    x: LinkInput, su: SortedUnion, r_sh_any, r_ns_any, r_sh_fine
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(parent, has_child) from the per-run first-wins candidates of
+    :func:`tree_channels`, broadcast over the sorted union."""
+    n = x.valid.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     # candidate VALUES are insertion-sequence ranks, not lane indices —
     # run-min then picks the first-INSERTED candidate (host first-wins)
     # regardless of where the ring cursor has wrapped to
     seq = idx if x.seq is None else x.seq.astype(jnp.int32)
     rank_to_idx = jnp.zeros(n, jnp.int32).at[seq].set(idx)
-    sent = 2 * n  # run-min "absent" sentinel
-    far = jnp.full((n,), sent, jnp.int32)
-    val_sh = jnp.concatenate([jnp.where(sharedv, seq, sent), far])
-    val_ns = jnp.concatenate([jnp.where(nonshared, seq, sent), far])
-    # query half carries the span\'s shared flag so the sorted-space
-    # selection can pick fallback-vs-preference without a second unsort
-    qsh = jnp.concatenate([jnp.zeros((n,), bool), sharedv])
-    uidx = jnp.arange(2 * n, dtype=jnp.int32)
-
-    # zt-lint: disable=ZT07 — fresh entrypoints reach this only through dependency_links' ctx=None fallback, which they never take (they always pass the delta ctx from fresh_link_context); the full-ring sort runs at rollup cadence / cold rebuilds only
-    sorted_ops = jax.lax.sort(
-        tuple(id_lanes) + (svc_lane, val_sh, val_ns, qsh, uidx), num_keys=4
-    )
-    s_ids = sorted_ops[:3]
-    s_svc, sh_s, ns_s, s_qsh, sord = sorted_ops[3:]
-
-    coarse = _run_starts(list(s_ids))
-    fine = coarse | jnp.asarray(segment_starts(s_svc))
-
-    rid_c = jnp.cumsum(coarse.astype(jnp.int32))
-    rid_f = jnp.cumsum(fine.astype(jnp.int32))
-    # all three run-min broadcasts ride ONE shift-doubling ladder
-    r_sh_any, r_ns_any, r_sh_fine = _run_min_ladder(
-        [(sh_s, rid_c), (ns_s, rid_c), (sh_s, rid_f)], sent
-    )  # any shared / first non-shared / shared with same service
+    has_parent = ((x.p0 | x.p1) != 0) & x.valid
+    sharedv = x.valid & x.shared
+    s_svc = su.keys[3]
 
     # Parent-id resolution in SpanNode._choose_parent preference order,
     # evaluated PER SORTED LANE: 1) first shared with the child\'s
@@ -294,15 +324,20 @@ def resolve_parents(x: LinkInput) -> Tuple[jnp.ndarray, jnp.ndarray]:
     # host builder\'s shared fallback consults only primary_by_id — no
     # endpoint preference); query lanes of normal spans take the full
     # preference chain
-    is_table = sord < n
-    combined = jnp.where(is_table | s_qsh, r_ns_any, by_parent_id)
+    is_table = su.order < n
+    combined = jnp.where(is_table | su.qsh, r_ns_any, by_parent_id)
 
     # ONE unsort: scatter the combined rank, convert rank -> lane index
-    inv = jnp.zeros(2 * n, jnp.int32).at[sord].set(combined)
+    inv = jnp.zeros(2 * n, jnp.int32).at[su.order].set(combined)
     un = jnp.where(inv >= 0, rank_to_idx[jnp.where(inv >= 0, inv, 0)], -1)
 
+    # ALL spans with parents query the parent-id join — including shared
+    # halves: a shared server span prefers its same-id client half, but
+    # when that mate is absent it must fall back to its parentId exactly
+    # like SpanNode.Builder does (found by the linker fuzz: a mateless
+    # shared span previously became a root and re-attributed its edge)
     j_shared = jnp.where(sharedv, un[:n], -1)
-    q = jnp.where(q_valid, un[n:], -1)
+    q = jnp.where(has_parent, un[n:], -1)
     parent = jnp.where(sharedv, jnp.where(j_shared >= 0, j_shared, q), q)
     # a span must not become its own parent (self-parent -> dangling root,
     # as the host builder treats a self-referential choice)
@@ -315,6 +350,29 @@ def resolve_parents(x: LinkInput) -> Tuple[jnp.ndarray, jnp.ndarray]:
         .max(jnp.where(parent >= 0, 1, 0))
     )
     return parent, has_child.astype(bool)
+
+
+def resolve_parents(x: LinkInput) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Tree edges from id joins: returns (parent_row [n] with -1 for roots,
+    has_child [n] bool).
+
+    Everything after :func:`sort_union`'s one sort is contiguous: run
+    boundaries are adjacent-lane compares, per-run first-wins candidates
+    are segmented min broadcasts, and the SpanNode._choose_parent
+    preference chain is evaluated in sorted space so only ONE combined
+    candidate needs un-permuting.
+
+    That shape is the r4 redesign of the fresh dependency read
+    (VERDICT r3 order 1): the r3 formulation un-permuted three
+    candidate arrays through gather/scatter passes and fixed-schedule
+    pointer chases, costing 145.8 ms captured device time at ring
+    capacity 2^18; this one measures 23.6 ms for the resolve and
+    34.3 ms for the full link context (chip A/B, bit-identical output).
+    """
+    su = sort_union(x)
+    # all three run-min broadcasts ride ONE shift-doubling ladder
+    mins = _run_min_ladder(tree_channels(su), 2 * x.valid.shape[0])
+    return choose_parents(x, su, *mins)
 
 
 def chase_ancestors(

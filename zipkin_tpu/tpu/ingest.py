@@ -383,7 +383,7 @@ def fresh_link_context(config: AggConfig, state: AggState) -> linker.LinkContext
     """The fresh-read link context via the incremental delta formulation:
     persistent ctx + since-advance delta segment, bit-identical to
     ``linker.link_context(ring_link_input(state))`` (the from-scratch
-    oracle) but without any full-ring sort."""
+    oracle) but without any full-ring sort (the rollup did that one)."""
     return delta_linker.delta_link_context(
         ring_link_input(state), ctx_struct(state), config.rollup_segment
     )
@@ -405,9 +405,10 @@ def rollup_step(config: AggConfig, state: AggState) -> AggState:
     span is ever overwritten without its links being preserved.
 
     ISSUE 5: this is also where the persistent incremental link ctx
-    ADVANCES — the delta-merge resolve doubles as the rollup's emit
-    context (one resolve serves both), and the refreshed ctx is what
-    makes the next fresh read pay only its own since-rollup delta.
+    ADVANCES — one sort of the whole join union (delta_linker.advance)
+    gives the rollup's emit context and the rebuilt ctx order alike, and
+    the refreshed ctx is what makes the next fresh read pay only its
+    own since-rollup delta.
     """
     x = ring_link_input(state)
     # x.seq is age-since-cursor: the lanes the cursor will overwrite next
