@@ -42,7 +42,7 @@ from launcher import TRANSPORT_ERRORS, Http, RunFailure  # noqa: E402
 DRAIN_LIMIT_S = 90.0  # an answer that comes late is late, not wrong
 OP_TIMEOUT_S = 120.0  # a POST of the window: part of what an operation is
 SETUP_TIMEOUT_S = 900.0  # a POST of the set-up: as long as its reads wait
-WARM_ROUNDS = 4
+WARM_ROUNDS = 6  # looks at the program table; a good warm-up takes 3
 
 
 class Client:
@@ -61,6 +61,8 @@ class Client:
         self.next_n = 0
         self.sends = []  # dicts, one per batch
         self.phase = "boot"  # then "setup", "window", "drain": run.py's names
+        self.marks = {}  # the set-up's parts, each by the moment it ended
+        self.publish_waits = []  # one entry for each ``after_publish``
 
     # ---- writes ----------------------------------------------------------
 
@@ -96,6 +98,7 @@ class Client:
             wait_ms = headers.get("X-Retry-After-Ms")
             time.sleep(float(wait_ms) / 1000.0 if wait_ms
                        else min(0.005 * rec["retries"], 0.25))
+        rec["ended"] = time.monotonic()  # with its answer or without
         with self.lock:
             self.sends.append(rec)
         return rec
@@ -218,22 +221,61 @@ class Client:
         per_roll = self.cfg["agg"]["ring_capacity"] // 2 // self.post_spans
         return (self.next_n - 1) % per_roll + 1 if self.next_n else 0
 
-    def after_publish(self, http: Http, watch: str) -> None:
-        """Until the program ``watch`` has run once more (the configuration
-        names one that only the read mirror's publish calls), 15 s at most:
-        the next publish is then a period away."""
-        seen = self.program_calls(http).get(watch, 0)
+    def publisher(self, http: Http) -> dict:
+        """The read mirror's publisher by its own counters. Its ticker looks
+        once a period and then does one of three things: it publishes
+        (something was written since its last epoch), skips (nothing was) or
+        backs off (paced behind a publish that took long)."""
+        c = http.get_json("/api/v2/tpu/counters")
+        return {k: c.get(k, 0) for k in ("mirrorPublishes",
+                                         "mirrorPublishSkips")}
+
+    def after_publish(self, http: Http) -> None:
+        """Until the publisher has looked once more and published or skipped:
+        its next look is then a period away, and the burst that is to fill
+        the digest buffer has that long before a publish empties it. A skip
+        serves as well as a publish: nothing was written since the last
+        epoch, so no publish comes before the next write, and waiting for one
+        (as this did until PR 35, by the calls of ``publish_program``) could
+        only run into its limit: 15 s, whenever the publish that the last
+        batch made due had run before the wait began. A back-off is waited
+        out: it lasts as long as the last publish did. A server that has
+        never published (no mirror) is not waited for."""
         t0 = time.monotonic()
-        while time.monotonic() - t0 < 15.0:
-            time.sleep(0.2)
-            if self.program_calls(http).get(watch, 0) > seen:
-                return
+        seen = self.publisher(http)
+        looked = False
+        while (seen["mirrorPublishes"] and not looked
+               and time.monotonic() - t0 < 15.0):
+            time.sleep(0.05)
+            looked = self.publisher(http) != seen
+        self.publish_waits.append(
+            {"s": time.monotonic() - t0, "looked": looked})
+
+    def floor(self) -> int:
+        """Batches every set-up sends at least: what a warm-up with one
+        spoiled round of the costlier kind sends (none where the
+        configuration gives no hints). ``pin_phase`` pads to it, so that the
+        window opens on the same number of batches whichever way the warm-up
+        went."""
+        if not (self.cfg.get("warm") or {}).get("step_programs"):
+            return 0
+        agg = self.cfg["agg"]
+        per_roll = agg["ring_capacity"] // 2 // self.post_spans
+        per_flush = agg["digest_buffer"] // self.post_spans
+        return 2 * (per_roll + min(per_roll, per_flush)) + per_flush + 2
+
+    def burst(self, http: Http, count: int) -> bool:
+        """``count`` batches that are to fill the digest buffer. -> whether a
+        publish emptied it on the way: such a round is spoiled, not idle."""
+        before = self.publisher(http)["mirrorPublishes"]
+        self.post_applied(count)
+        return self.publisher(http)["mirrorPublishes"] > before
 
     def warm_steps(self):
         """Drive the write path at the cell's own POST size so that no variant
         of the device step compiles (or is read from the compile cache, which
         also takes seconds) inside the window.
-        -> (rounds, hinted programs never reached).
+        -> (rounds, hinted programs never reached, rounds a publish spoiled).
 
         Whatever the program, this sends one batch and then goes on to the
         ring fill, which alone takes the step through two roll-ups. Where the
@@ -244,59 +286,71 @@ class Client:
         since the last roll-up (this client's own count) and lanes since the
         last flush (a fresh percentile read, ``flush_by``, zeroes it; so does
         every publish of the read mirror, which is why the burst that is to
-        fill the buffer starts right after one). ``step_programs`` names the
-        variants as ``statusz`` lists them, only so that the loop knows when
-        to stop: a name that never shows up costs one round, then the loop
-        ends for lack of progress, and nothing fails. A configuration for a
-        program with one step shape leaves ``warm`` out."""
+        fill the buffer starts right after the publisher has looked).
+        ``step_programs`` names the variants as ``statusz`` lists them, only
+        so that the loop knows when to stop: a round that reached nothing new
+        and that no publish spoiled ends the loop for lack of progress (the
+        hints name nothing of this program), and nothing fails. A
+        configuration for a program with one step shape leaves ``warm`` out."""
         warm = self.cfg.get("warm") or {}
         http = Http(self.port, SETUP_TIMEOUT_S)
         self.post_applied(1)
         wanted = warm.get("step_programs") or []
         if not wanted:
             http.close()
-            return 0, []
+            return 0, [], 0
         agg = self.cfg["agg"]
         per_roll = agg["ring_capacity"] // 2 // self.post_spans
         per_flush = agg["digest_buffer"] // self.post_spans
-        missing, rounds = list(wanted), 0
+        both_burst = min(per_roll, per_flush)
+        missing, rounds, spoiled, spoiled_last = list(wanted), 0, 0, False
         for rounds in range(1, WARM_ROUNDS + 1):
             calls = self.program_calls(http)
-            missing = [p for p in wanted if not calls.get(p)]
-            if not missing or (rounds > 1 and len(missing) == len(wanted)):
+            reached = [p for p in missing if calls.get(p)]
+            missing = [p for p in missing if p not in reached]
+            if not missing or (rounds > 1 and not reached
+                               and not spoiled_last):
                 break  # all reached, or the hints name nothing of this program
+            spoiled_last = False
             to_edge = per_roll - self.since_rollup()  # batches that still fit
             if any(p.endswith("rollup") for p in missing):
                 # to the edge, flush by a read, one more: the roll-up alone.
                 # Both counts now run together, and a burst of per_roll more
                 # ends in flush-and-roll-up, unless a publish flushes in
-                # between: so start right after one
+                # between: so start right after the publisher has looked
                 self.post_applied(to_edge)
                 both = any("flush" in p for p in missing)
-                if both and warm.get("publish_program"):
-                    self.after_publish(http, warm["publish_program"])
+                if both:
+                    self.after_publish(http)
                 http.get_json(warm["flush_by"])
                 self.post_applied(1)
                 if both:
-                    self.post_applied(min(per_roll, per_flush))
+                    spoiled_last = self.burst(http, both_burst)
             else:
                 # the flush alone: zero its count away from the edge
                 if to_edge in (0, per_roll):
                     self.post_applied(1)
+                self.after_publish(http)
                 http.get_json(warm["flush_by"])
-                self.post_applied(per_flush + 1)
+                spoiled_last = self.burst(http, per_flush + 1)
+            spoiled += spoiled_last
+        else:
+            calls = self.program_calls(http)  # what the last round reached
+            missing = [p for p in missing if not calls.get(p)]
         http.close()
-        return rounds, missing
+        return rounds, missing, spoiled
 
     def pin_phase(self) -> int:
         """Leave the maintenance in one known phase before the window opens,
-        whatever the warm-up needed: half a roll-up period since the last
-        roll-up and, where the configuration says how, nothing pending since
-        the last flush. Runs that started in different phases settled into
-        rates 5-10% apart (PERF.md section 6). -> batches sent."""
+        whatever the warm-up needed: ``floor()`` batches sent or, past
+        that, the next count that is half a roll-up period since the last
+        roll-up; and, where the configuration says how, nothing pending
+        since the last flush. Runs that started in different phases settled
+        into rates 5-10% apart (PERF.md section 6). -> batches sent."""
         per_roll = self.cfg["agg"]["ring_capacity"] // 2 // self.post_spans
         http = Http(self.port, SETUP_TIMEOUT_S)
-        pad = (per_roll // 2 - self.since_rollup()) % per_roll
+        pad = max(0, self.floor() - self.next_n)
+        pad += (per_roll // 2 - self.since_rollup() - pad) % per_roll
         self.post_applied(pad)
         flush_by = (self.cfg.get("warm") or {}).get("flush_by")
         if flush_by:
@@ -324,13 +378,18 @@ class Client:
     def run(self) -> dict:
         seconds = float(self.spec["seconds"])
         self.wait_health()
+        time.sleep(float(self.spec.get("late_s", 0.0)))
+        self.marks["health"] = time.monotonic()
         self.phase = "setup"
         # set-up: every step variant at the cell's own POST size, then the
         # rest of one ring's worth, so the window runs with eviction
-        warm_rounds, warm_missing = self.warm_steps()
+        warm_rounds, warm_missing, warm_spoiled = self.warm_steps()
+        self.marks["warm"] = time.monotonic()
         fill = -(-int(self.wl["fill_spans"]) // self.post_spans)
         self.post_applied(max(0, fill - self.next_n))
+        self.marks["fill"] = time.monotonic()
         self.pin_phase()
+        self.marks["pin"] = time.monotonic()
         # the window starts with the device's queue empty, and the read that
         # will close it has run (and compiled) once
         self.device_sync(time.monotonic(), SETUP_TIMEOUT_S)
@@ -357,6 +416,8 @@ class Client:
             "before": before, "after": after,
             "drain_limit_s": DRAIN_LIMIT_S,
             "warm_rounds": warm_rounds, "warm_missing": warm_missing,
+            "warm_spoiled": warm_spoiled,
+            "marks": self.marks, "publish_waits": self.publish_waits,
         }
 
 

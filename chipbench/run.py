@@ -210,6 +210,13 @@ def main() -> int:
     ap.add_argument("--control", action="store_true",
                     help="also put the reference in the program's place, "
                     "sound and with a guarantee broken (control.py)")
+    ap.add_argument("--late", type=float, default=0.0,
+                    help="the client waits this long after /health before "
+                    "its first POST: moves the set-up against the server's "
+                    "tickers (it counts in setup_s)")
+    ap.add_argument("--record",
+                    help="keep the client's record (every send with its "
+                    "times) in this file, for measures.py's sub-windows")
     args = ap.parse_args()
     bench, cell, config, workload = load_cell(args.workload)
     traced = bool(args.trace)
@@ -232,7 +239,7 @@ def main() -> int:
         # server boots; so does this process, for the reference
         spec = {"port": server.port, "seed": args.seed,
                 "seconds": args.seconds, "config": config,
-                "workload": workload,
+                "workload": workload, "late_s": args.late,
                 "out": os.path.join(workdir, "client.json")}
         with open(os.path.join(workdir, "spec.json"), "w") as f:
             json.dump(spec, f)
@@ -276,6 +283,8 @@ def main() -> int:
                 f"the client exited with {client.returncode}: "
                 + (client_failure or {}).get("reason", "it did not say why"))
         result = load_json(spec["out"])
+        if args.record:
+            shutil.copyfile(spec["out"], args.record)
 
         device = read_device(server)  # the peak, before anything else runs
         settle_s = settle(server, config, result)
@@ -333,6 +342,7 @@ def main() -> int:
                                  "idle_gaps": xplane["idle_gaps"][:10]}
         line["notes"] = dict(ops, e2e=e2e, settle_s=settle_s,
                              compare_detail=detail,
+                             **measures.setup_parts(result, T_START),
                              **setup_waits(programs_at_health, result))
         if args.control:
             import control
