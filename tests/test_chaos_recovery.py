@@ -10,8 +10,7 @@ Crash simulation uses action="raise": ``CrashpointTriggered``
 propagates out of the write path and the store object is abandoned —
 the same HBM-is-gone idiom as tests/test_wal.py, with the addition
 that the armed site flushes its partial write first so the on-disk
-tear is exactly what a SIGKILL after a real flush would leave. The
-SIGKILL-subprocess variant of this harness is benchmarks/chaos_soak.py.
+tear is exactly what a SIGKILL after a real flush would leave.
 
 Tier-1 runs the deterministic single-site tests; the randomized
 multi-site soak (>=20 kill/restart cycles) is marked slow.

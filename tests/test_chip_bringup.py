@@ -71,19 +71,6 @@ def test_make_mesh_accepts_cpu_when_asked_and_tpu_always(monkeypatch):
     mesh_mod.require_tpu([_FakeDevice("tpu")])  # no raise
 
 
-def test_pallas_hll_off_a_tpu_raises(monkeypatch):
-    import jax.numpy as jnp
-
-    from zipkin_tpu.tpu import ingest as ing
-
-    monkeypatch.setenv("TPU_PALLAS_HLL", "1")
-    with pytest.raises(RuntimeError, match="TPU_PALLAS_HLL"):
-        ing._hll_update(
-            jnp.zeros((8, 16), jnp.uint8), jnp.zeros((4,), jnp.int32),
-            jnp.zeros((4,), jnp.uint32), jnp.ones((4,), bool),
-        )
-
-
 @pytest.fixture
 def cache_config():
     """Restore whatever the helper sets on jax.config."""

@@ -138,21 +138,6 @@ def test_merge_reads(one_chip, name):
     _fits(one_chip["raw"][name].lower(one_chip["state"]).compile())
 
 
-def test_pallas_hll_is_a_tpu_kernel(one_chip):
-    from zipkin_tpu.ops import pallas_hll
-
-    one = SingleDeviceSharding(one_chip["device"])
-    regs = one_chip["leaf"].hll  # (max_services + 1, 2^p) u8
-    assert regs.shape == (1025, 2048) and regs.dtype == jnp.uint8
-    compiled = pallas_hll.update.lower(
-        jax.ShapeDtypeStruct(regs.shape, regs.dtype, sharding=one),
-        jax.ShapeDtypeStruct((LANES,), jnp.int32, sharding=one),
-        jax.ShapeDtypeStruct((LANES,), jnp.uint32, sharding=one),
-        jax.ShapeDtypeStruct((LANES,), jnp.bool_, sharding=one),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.fixture(scope="module")
 def four_chips(topo, no_persistent_cache):
     """Read programs and the state's shapes on a 4-device described mesh."""

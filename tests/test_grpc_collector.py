@@ -94,7 +94,7 @@ def test_server_config_enables_grpc():
 def test_server_grpc_collector_gets_fast_ingest():
     """The gRPC tier's Collector must carry the fast-ingest flag: without
     it proto3 Report payloads decode on the Python object path (~15k
-    spans/s measured) while HTTP rides the native parser (r5 server_bench
+    spans/s measured) while HTTP rides the native parser (an r5
     finding)."""
     import asyncio as _asyncio
 

@@ -166,7 +166,7 @@ def test_zt03_allows_lru_cached_factory(tmp_path):
 
 def test_zt03_jit_decorator_is_not_a_construction_site(tmp_path):
     # regression: @functools.partial(jax.jit, ...) evaluates at def
-    # time, not per call (ops/pallas_hll.py shape)
+    # time, not per call
     result = lint(
         tmp_path,
         """
@@ -350,7 +350,7 @@ def test_zt06_flags_blocking_sync_in_serving_code(tmp_path):
 
 
 def test_zt06_exempts_benchmarks_and_tests(tmp_path):
-    for name in ("benchmarks/bench.py", "tests/test_x.py"):
+    for name in ("chipbench/run.py", "tests/test_x.py"):
         assert rules(lint(tmp_path, ZT06_POSITIVE, name=name)) == []
 
 

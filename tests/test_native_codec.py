@@ -125,7 +125,8 @@ class TestFastIngest:
         np.testing.assert_array_equal(h_slow, h_fast)
         np.testing.assert_array_equal(r_slow, r_fast)
 
-    def test_collector_uses_fast_path_and_samples(self):
+    @staticmethod
+    def _collector(rate):
         from zipkin_tpu.collector.core import Collector, CollectorSampler
         from zipkin_tpu.collector.core import InMemoryCollectorMetrics
         from zipkin_tpu.tpu.state import AggConfig
@@ -137,9 +138,15 @@ class TestFastIngest:
         store = TpuStorage(config=cfg, pad_to_multiple=256)
         metrics = InMemoryCollectorMetrics()
         collector = Collector(
-            store, sampler=CollectorSampler(0.2),
+            store, sampler=CollectorSampler(rate),
             metrics=metrics.for_transport("http"), fast_ingest=True,
         )
+        return store, metrics, collector
+
+    def test_collector_uses_fast_path_and_samples(self):
+        from zipkin_tpu.collector.core import CollectorSampler
+
+        _, metrics, collector = self._collector(0.2)
         spans = lots_of_spans(2000, seed=15)
         data = json_v2.encode_span_list(spans)
         accepted = collector.accept_spans_bytes(data)
@@ -149,6 +156,19 @@ class TestFastIngest:
         # sampling must agree exactly with the scalar sampler
         want = sum(1 for s in spans if CollectorSampler(0.2).test(s))
         assert accepted == want
+
+    def test_payload_the_parser_refuses_is_sampled_too(self):
+        """An escaped string sends the payload down the object path, which
+        must sample like the fast path: at rate 0 nothing is ingested."""
+        store, metrics, collector = self._collector(0.0)
+        data = json_v2.encode_span_list(TRACE).replace(
+            b"get /", b"get \\u002f")
+        assert store.ingest_json_fast(data) is None  # the parser's bail-out
+        assert collector.accept_spans_bytes(data) == 0
+        assert metrics.get("spans", "http") == len(TRACE)
+        assert metrics.get("spans_dropped", "http") == len(TRACE)
+        assert store.ingest_counters()["spans"] == 0
+        assert store.get_trace(TRACE[0].trace_id).execute() == []
 
 
 class TestMixedPathCoherence:

@@ -332,6 +332,31 @@ def test_rollup_detects_hll_drift():
     )
 
 
+def test_rollup_detects_digest_drift():
+    # an undersized digest on a bimodal stream: four equal-weight
+    # centroids put the slow tenth into one cluster with fast spans,
+    # and the shadow's exact tail says so on the alerting gauge
+    n = 400
+    durs = np.where(np.arange(n) % 10 == 9, 100_000, 1_000)
+    cols = _client_server_lanes(n, durs)
+    shadow = HostShadow(reservoir_k=512, link_rate=0.0, seed=10)
+    shadow.offer_cols(cols)
+    vocab = FakeVocab([(0, 0), (1, 0), (2, 0)],
+                      {1: "frontend", 2: "backend"})
+    agg = DeviceAgg(durs, distinct=n, edges=[], max_services=64,
+                    spans=2 * n)
+    agg._digest = np.zeros((3, 4, 2))
+    agg._digest[1, :, 0] = np.sort(durs).reshape(4, -1).mean(axis=1)
+    agg._digest[1, :, 1] = n / 4
+    acc = AccuracyEstimator(DeviceStore(agg, vocab, 64), shadow,
+                            rollup_s=0.0)
+    g = acc.rollup()
+    # the stated bound widens with the clusters; the drift gauge leaves
+    # their width out and crosses the default spec's limit
+    assert g["accuracyDigestP99RelErr"] <= g["accuracyDigestP99Bound"]
+    assert g["accuracyDigestP99Drift"] > 0.20
+
+
 def test_rollup_names_itself_in_the_lock_ledger():
     """The rollup's device reads each take the aggregator lock: they run
     under the holder label ``accuracy_rollup``, not ``unattributed``."""

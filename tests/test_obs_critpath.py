@@ -251,7 +251,7 @@ def test_sigkilled_worker_slots_reclaimed_no_stuck_timelines():
 
 
 def test_littles_law_gauges_nonzero_after_driven_load():
-    """Regression for INGEST_r08's all-zero gauge columns: waterfall()
+    """Regression for the r08 run's all-zero gauge columns: waterfall()
     runs its own stitch, and when that stitch folds nothing (the load
     just drained — the report path's usual timing) the old code zeroed
     all four gauges before reading them. Post-fix, the gauges keep the

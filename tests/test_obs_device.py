@@ -87,7 +87,7 @@ def test_wrapper_preserves_lower_and_wrapped():
     inner = toy_program()
     fn = obs.wrap("toy_double", inner)
     assert fn.__wrapped__ is inner
-    # benchmarks AOT-compile programs directly via .lower()
+    # test_chip_compile AOT-compiles programs directly via .lower()
     compiled = fn.lower(jnp.zeros(4, jnp.float32)).compile()
     assert compiled is not None
 

@@ -145,6 +145,10 @@ def test_latency_slo_trips_on_burn_and_clears_on_recovery():
     assert v["alert"]
     assert v["windows"]["4s"]["burn"] >= 2.0
     assert h.dog.trips == 1
+    # and /prometheus shows it burning (the server's renderer)
+    from zipkin_tpu.server.app import _prom_slo
+
+    assert 'zipkin_tpu_slo_alert{slo="q_p99"} 1' in _prom_slo(h.dog.verdicts())
     # recovery: healthy ticks push the burn out of both windows
     for _ in range(9):
         for _ in range(20):

@@ -403,7 +403,7 @@ class TestSustainedFlood:
     def test_flood_sheds_with_guidance_zero_acked_loss_b0_recovery(
         self, tmp_path
     ):
-        """The EVALS config8 shape: >= 3x queue capacity through the
+        """The overload gate: >= 3x queue capacity through the
         real HTTP boundary while the device feed is slow AND the WAL
         hits ENOSPC mid-flood. Every shed must carry backoff guidance;
         every 202 must survive to durable parity; the disk-full window

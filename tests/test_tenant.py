@@ -436,6 +436,65 @@ class TestPromTenantFamilies:
         assert _prom_tenants({"tenants": None}) == []
 
 
+# -- the scoped shed at the real boundaries ---------------------------------
+
+
+class TestBoundaryScopedShed:
+    def test_http_429_names_the_tenant_and_spares_the_others(self):
+        from tests.test_overload import bulk_payload, run_server
+        from zipkin_tpu.runtime.tenant import TENANT_HEADER
+        from zipkin_tpu.server.config import ServerConfig
+
+        body = bulk_payload(1, per=8)
+
+        async def scenario(client, server):
+            async def post(tenant):
+                return await client.post(
+                    "/api/v2/spans", data=body,
+                    headers={"Content-Type": "application/json",
+                             TENANT_HEADER: tenant})
+
+            assert (await post("B")).status == 202
+            shed = await post("B")
+            assert shed.status == 429
+            assert shed.headers["X-Shed-Scope"] == "tenant"
+            assert shed.headers["X-Shed-Tenant"] == "B"
+            assert int(shed.headers["Retry-After"]) >= 1
+            assert int(shed.headers["X-Retry-After-Ms"]) > 0
+            for other in ("A", "C"):
+                assert (await post(other)).status == 202
+            counters = server._overload.counters()
+            assert counters["overloadLevel"] == B0
+            assert counters["overloadTransitions"] == 0
+            statusz = await (await client.get("/api/v2/tpu/statusz")).json()
+            tenants = statusz["overload"]["tenants"]["tenants"]
+            assert tenants["B"]["level"] >= 2
+            assert tenants["A"]["level"] == 0
+            prom = await (await client.get("/prometheus")).text()
+            assert 'zipkin_tpu_tenant_level{tenant="B"} 2' in prom
+
+        # a burst of one and a half payloads: B's second POST sheds
+        run_server(scenario, config=ServerConfig(
+            tenant_ingest_bytes_per_s=1.5 * len(body),
+            tenant_ingest_burst_s=1.0,
+        ))
+
+    def test_grpc_trailers_name_scope_and_tenant(self):
+        import types
+
+        from zipkin_tpu.server.grpc import _SpanServiceHandler
+        from zipkin_tpu.tpu.mp_ingest import IngestBackpressure
+
+        handler = _SpanServiceHandler(
+            types.SimpleNamespace(overload=OverloadController()))
+        trailers = dict(handler._retry_trailers(IngestBackpressure(
+            "over budget", scope="tenant", tenant="B", retry_after_s=0.25)))
+        assert trailers["shed-scope"] == "tenant"
+        assert trailers["shed-tenant"] == "B"
+        assert trailers["retry-delay"] == "0.250s"
+        assert trailers["retry-delay-ms"] == "250"
+
+
 # -- tenant attribution through the MP fan-out tier -------------------------
 
 

@@ -4,7 +4,7 @@ The durability claims in ARCHITECTURE.md ("every 202-acked batch
 replays after kill -9") are only as good as the crash *timing* they
 were tested under. This registry names the exact instants inside the
 write path where a crash is most likely to tear on-disk state, so the
-chaos driver (tests/test_chaos_recovery.py, benchmarks/chaos_soak.py)
+chaos driver (tests/test_chaos_recovery.py)
 can kill the process AT each of them instead of at whatever instant a
 timer happens to land on:
 
@@ -76,7 +76,7 @@ tenant), or the ambient ``CURRENT_TENANT`` contextvar at boundary
 sites. Non-matching traversals do NOT consume ``nth``/``count``, so a
 fault armed for tenant B stays armed through any amount of A/C
 traffic — the deterministic per-tenant injection the isolation tests
-(tests/test_tenant.py, EVALS config9) are built on.
+(tests/test_tenant.py) are built on.
 
 The disarmed fast path is one dict probe, so production code keeps the
 hooks compiled in; a site is one-shot — it disarms itself as it fires

@@ -1,17 +1,17 @@
 """ZT06 — blocking sync on serving paths.
 
 ``block_until_ready()`` stalls the calling thread until every queued
-device computation retires. In benchmarks and evals that is the point
+device computation retires. In a benchmark that is the point
 (wall-clock honesty); on a serving path it serializes the async ingest
 pipeline behind the device and hands the transport's fixed round trip
 to the caller. The ingest/read planes are designed to overlap host and
-device work (AsyncIngestFeeder's pipeline stages, the lock-scoped
+device work (the device queue's run-ahead, the lock-scoped
 dispatch-then-pull split in state_clone) — a stray sync undoes that
 silently.
 
 Rule: any ``*.block_until_ready()`` (or ``jax.block_until_ready(x)``)
-call in library code — paths under ``benchmarks/``, ``evals/`` and
-``tests/`` are exempt, as is the body of a method itself NAMED
+call in library code — paths under ``chipbench/`` and ``tests/`` are
+exempt, as is the body of a method itself NAMED
 ``block_until_ready`` (that is the deliberate sync seam the exempt
 callers use). Legitimate library blockers (health checks, drain seams,
 warm-up) carry a scoped pragma naming why blocking is the contract.
@@ -24,7 +24,7 @@ import ast
 from zipkin_tpu.lint.core import Checker, Module, register
 
 _FUNC_KINDS = (ast.FunctionDef, ast.AsyncFunctionDef)
-_EXEMPT_PATH_PARTS = ("benchmarks/", "evals/", "tests/", "test_")
+_EXEMPT_PATH_PARTS = ("chipbench/", "tests/", "test_")
 
 
 @register
@@ -32,7 +32,7 @@ class BlockingSync(Checker):
     rule = "ZT06"
     severity = "error"
     name = "blocking-sync"
-    doc = "block_until_ready outside benchmarks/evals/tests"
+    doc = "block_until_ready outside chipbench/tests"
     hint = (
         "let the async pipeline overlap host and device work; if "
         "blocking IS the contract (drain/health/warm-up), suppress on "

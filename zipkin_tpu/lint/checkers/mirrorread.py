@@ -2,7 +2,7 @@
 
 ISSUE 14's tentpole took the query path off the aggregator lock: the
 epoch-published read mirror (``tpu/mirror.py``) serves immutable
-snapshots behind a seqlock generation stamp, and QUERY_SLO_r08's whole
+snapshots behind a seqlock generation stamp, and the tier's whole
 p99 claim rests on the serve path never blocking. The regression shape
 this rule fences is quiet and plausible-looking: someone "just adds" a
 live-counter touch or a cache probe to the serve path, the call chain

@@ -13,11 +13,13 @@ codec, which is the semantic reference.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import hashlib
 import logging
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional
 
@@ -41,22 +43,34 @@ def _compile() -> Optional[str]:
     if os.path.exists(so_path):
         return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = so_path + ".tmp"
-    for cc in ("cc", "gcc", "clang"):
-        try:
-            subprocess.run(
-                [cc, "-O2", "-fPIC", "-shared", "-o", tmp, _SRC],
-                check=True, capture_output=True, timeout=120,
-            )
+    # A temporary name of this process's own: processes that build at once
+    # (test workers, two servers on one checkout) would otherwise rename
+    # each other's half-written file into place.
+    fd, tmp = tempfile.mkstemp(dir=_BUILD_DIR, suffix=".so.tmp")
+    os.close(fd)
+    try:
+        for cc in ("cc", "gcc", "clang"):
+            try:
+                subprocess.run(
+                    [cc, "-O2", "-fPIC", "-shared", "-o", tmp, _SRC],
+                    check=True, capture_output=True, timeout=120,
+                )
+            except FileNotFoundError:
+                continue
+            except subprocess.CalledProcessError as e:
+                if os.path.exists(so_path):  # another process won
+                    return so_path
+                logger.warning(
+                    "native codec build failed with %s: %s", cc, e.stderr
+                )
+                return None
             os.replace(tmp, so_path)
             return so_path
-        except FileNotFoundError:
-            continue
-        except subprocess.CalledProcessError as e:
-            logger.warning("native codec build failed with %s: %s", cc, e.stderr)
-            return None
-    logger.warning("no C compiler found; native codec disabled")
-    return None
+        logger.warning("no C compiler found; native codec disabled")
+        return None
+    finally:
+        with contextlib.suppress(OSError):  # gone already if renamed
+            os.unlink(tmp)
 
 
 def _load():

@@ -530,7 +530,7 @@ class CritPathStitcher:
                 self.reclaimed += 1
         # Little's law over this stitch window: L = lambda * W. The
         # gauges describe the most recent non-idle window; an idle tick
-        # KEEPS them (INGEST_r08 read all zeros because the report-path
+        # KEEPS them (the r08 ingest run read all zeros: the report-path
         # stitch after a drained load was always idle and clobbered the
         # real window) and only a sustained idle spell past the
         # staleness horizon zeroes them, so a stale saturation reading

@@ -529,7 +529,7 @@ class DeviceObservatory:
         wrapper.__name__ = name
         wrapper.__wrapped__ = fn
         wrapper.program_stats = entry
-        # AOT path stays reachable (benchmarks lower() programs directly)
+        # AOT path stays reachable (test_chip_compile lower()s programs)
         lower = getattr(fn, "lower", None)
         if lower is not None:
             wrapper.lower = lower

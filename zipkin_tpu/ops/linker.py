@@ -104,8 +104,8 @@ def _run_min_ladder(channel_runs, none: int):
     one fused elementwise kernel (min over self + left/right neighbor
     at distance d, guarded by run-id equality) over ALL channels.
 
-    This replaces the associative-scan formulation (r5 chip A/B,
-    benchmarks/resolve_variants.py + PROFILE_r05): the scans' tree
+    This replaces the associative-scan formulation (r5 chip A/B, before
+    the chip benchmark existed, not re-read): the scans' tree
     sweeps cost ~15 ms of the 23.6 ms resolve at ring 2^18 and resisted
     every restructuring (channel fusion, reverse=True, forward-only
     dual-sort all measured flat or worse — XLA already CSEs identical
@@ -176,7 +176,7 @@ def _seg_min_scan(vals, flags, reverse=False):
     """Segmented inclusive min scan over contiguous runs (reset where
     ``flags``). The scans replace the scatter-min/gather formulation:
     at ring capacity 2^18 the scatter variant measured 59.3 ms for the
-    whole resolve vs 23.6 ms with scans (benchmarks r4 A/B on chip)."""
+    whole resolve vs 23.6 ms with scans (r4 A/B on chip)."""
 
     def combine(a, b):
         fa, va = a
@@ -388,7 +388,7 @@ def chase_ancestors(
     nearest-kinded-ancestor-or-self relation. A fixed
     ceil(log2(n)) schedule costs 19 passes at ring capacity 2^18 —
     70.6 ms captured device time, HALF the 145.8 ms fresh link-context
-    rebuild (benchmarks/profile_link_ctx.py) — yet real trace forests
+    rebuild (r3 profile of the rebuild's parts) — yet real trace forests
     are tens deep, converged after 5-8 passes. The lax.while_loop stops
     at the fixed point (captured: 10.7 ms, 6.6x) and stays EXACT for
     any depth: the fixed pass count remains as a bound only so

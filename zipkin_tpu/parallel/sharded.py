@@ -509,7 +509,7 @@ def _compiled_programs(config: AggConfig, mesh: Mesh):
     ttread = _packed(ttread_sm, "spmd_ttread")
 
     # the pre-pack (multi-output) jits, kept compilable for the packed
-    # wire parity tests and the transfers-3→1 A/B in benchmarks — jit is
+    # wire parity tests and tests/test_chip_compile.py — jit is
     # lazy, so an un-dispatched raw variant costs nothing
     raw = {
         "merge": jax.jit(merge_sm),

@@ -8,13 +8,13 @@ against a rolling baseline of healthy windows, and when the rate stays
 collapsed (or a wall deadline arrives) it drains in-flight device
 work, takes a snapshot (which truncates covered WAL segments), and
 tells the host loop to exit with :data:`EX_RESTART` so an outer driver
-(evals/resume_driver.py, systemd, k8s) relaunches it against the same
+(a shell loop, systemd, k8s) relaunches it against the same
 resume dir — boot restore then continues the run with zero acked-span
 loss.
 
 Two ways to drive it:
 
-- **passive** (deterministic, used by evals + tests): the ingest loop
+- **passive** (deterministic, used by the tests): the ingest loop
   calls :meth:`ResumeSupervisor.observe` with the cumulative span
   count after each batch; a non-None return is the trip reason and the
   loop should call :meth:`finalize` and exit.

@@ -1263,7 +1263,7 @@ class ZipkinServer:
                     out[f"gauge.zipkin_tpu.{name}"] = counters[name]
             # epoch-published read mirror (ISSUE 14): publish cadence,
             # serve tallies, and staleness-at-serve — the gauges the
-            # query_mirror_staleness SLO and the r08 bench read
+            # query_mirror_staleness SLO reads
             for name in (
                 "mirrorGeneration", "mirrorPublishes", "mirrorPublishSkips",
                 "mirrorPublishBackoffs",

@@ -57,7 +57,7 @@ def _stamped_request(data: bytes):
     grpc's C core assembles the request message (socket reads, HTTP/2
     reassembly, the ~5 MB body of a 64k-span ListOfSpans) BEFORE the
     Python handler runs, so a ``t0`` taken inside ``report()`` misses
-    the read entirely — INGEST_r07 showed ``grpc_boundary`` at 0.16 µs
+    the read entirely — the r07 ingest run showed ``grpc_boundary`` at 0.16 µs
     vs ``http_boundary``'s 0.73 µs for identical proto3 work. The
     deserializer is the earliest Python hook after assembly: stamping
     here makes the stage span request read + decode like the HTTP

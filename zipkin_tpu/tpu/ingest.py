@@ -11,7 +11,6 @@ padding). Reads are pure functions over the same state.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -49,25 +48,6 @@ def lane_bucket(lanes: int, pad_to_multiple: int, cap: int) -> int:  # zt-dispat
     return min(b, cap) if cap >= lanes else b
 
 
-def _hll_update(registers, rows, hashes, valid):
-    """HLL update with the opt-in Pallas backend (TPU_PALLAS_HLL=1).
-
-    Measured ~11% faster than the XLA scatter on a v5e chip but <1% of
-    the ingest step — see ops/pallas_hll.py for the evidence and why the
-    XLA path stays the default."""
-    if os.environ.get("TPU_PALLAS_HLL", "") in ("1", "true"):
-        if jax.default_backend() != "tpu":
-            raise RuntimeError(
-                "TPU_PALLAS_HLL=1 asks for the Pallas HLL kernel, which "
-                f"runs only on a TPU; the backend is "
-                f"{jax.default_backend()!r}. Unset it to take the XLA path."
-            )
-        from zipkin_tpu.ops import pallas_hll
-
-        return pallas_hll.update(registers, rows, hashes, valid)
-    return hll.update(registers, rows, hashes, valid)
-
-
 def ingest_step(config: AggConfig, state: AggState, batch: SpanColumns) -> AggState:
     """Fold one columnar batch into the aggregate state (pure, jit-safe).
 
@@ -79,8 +59,8 @@ def ingest_step(config: AggConfig, state: AggState, batch: SpanColumns) -> AggSt
     # --- HLL: distinct traces per service + globally --------------------
     h = hashing.fmix32(batch.trace_h)
     svc_rows = jnp.clip(batch.svc, 0, config.max_services - 1)
-    new_hll = _hll_update(state.hll, svc_rows, h, valid & (batch.svc > 0))
-    new_hll = _hll_update(
+    new_hll = hll.update(state.hll, svc_rows, h, valid & (batch.svc > 0))
+    new_hll = hll.update(
         new_hll, jnp.full((n,), config.global_hll_row, jnp.int32), h, valid
     )
 
@@ -119,8 +99,8 @@ def ingest_step(config: AggConfig, state: AggState, batch: SpanColumns) -> AggSt
         tb_hll = jnp.where(tb_wipe[:, None, None], jnp.uint8(0), state.tb_hll)
         rows_flat = sl_tt * config.hll_rows + svc_rows
         flat = tb_hll.reshape(w_tt * config.hll_rows, -1)
-        flat = _hll_update(flat, rows_flat, h, tb_keep & (batch.svc > 0))
-        flat = _hll_update(
+        flat = hll.update(flat, rows_flat, h, tb_keep & (batch.svc > 0))
+        flat = hll.update(
             flat, sl_tt * config.hll_rows + config.global_hll_row, h, tb_keep
         )
         tt = dict(

@@ -1,6 +1,6 @@
 """Epoch-published read mirror: lock-free concurrent query serving.
 
-QUERY_SLO_r07 proved the read path was lock-bound, not device-bound:
+The r07 query run proved the read path lock-bound, not device-bound:
 with 8 reader threads, ``lock_wait`` was 77.5% of attributed query time
 (waiter high-water 7/8, device only 13.8%) and query_wall p99 was
 136.8 ms against the 50 ms north-star. The fix is the "Fast Concurrent

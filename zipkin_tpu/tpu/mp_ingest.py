@@ -4,7 +4,8 @@ The reference scales ingest horizontally with N collector workers/nodes
 (Kafka partition parallelism, ``KafkaCollector.java`` — SURVEY.md §2.8);
 under CPython one process cannot: the r2 profile measured the device path
 at ~490k spans/s/chip with the host parse GIL-serialized, and a threaded
-feeder measured SLOWER (tpu/feeder.py). This module is the multi-process
+pipeline in one process measured SLOWER than the synchronous loop
+(PERF.md section 6). This module is the multi-process
 fan-out tier (ISSUE 8, rebuilt around the span ring in ISSUE 16), the
 collector's real fast path for both JSON v2 and proto3 payloads over
 HTTP and gRPC:
@@ -25,7 +26,7 @@ HTTP and gRPC:
   aggregator's lane cap) become ONE ``concat_remap`` gather into a
   bucket-padded image + ONE jitted ingest step + ONE WAL record, acked
   together — amortizing the ~16 µs/span per-chunk dispatch overhead
-  INGEST_r08 measured. The chunk image is consumed as a zero-copy view
+  the r08 run measured. The chunk image is consumed as a zero-copy view
   into its ring slot; the coalesce gather (or, at ``coalesce_max=1``,
   the same per-chunk copy+remap as before) is the only copy it takes.
   WAL append and sampling verdicts ride ``ingest_fused`` on this side,

@@ -625,8 +625,8 @@ class TpuStorage(
         """Host half of the fast path: native parse + intern + sample +
         chunk + columnar pack. Returns (accepted, dropped, [(parsed,
         cols), ...]) or None for payloads the fast parser can't take.
-        Split from :meth:`_fast_dispatch` so AsyncIngestFeeder can run
-        the two halves in separate pipeline stages."""
+        Split from :meth:`_fast_dispatch`, the device half:
+        :meth:`warm` runs this half alone."""
         from zipkin_tpu import native
         from zipkin_tpu.tpu.columnar import pack_parsed
 
