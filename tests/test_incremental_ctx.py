@@ -1,20 +1,20 @@
-"""Incremental link context vs. the from-scratch oracle (ISSUE 5).
+"""The roll-up's incremental link context vs. the from-scratch oracle.
 
-The tentpole claim: the persistent ctx leaves maintained by
-``rollup_step`` (via ``delta_linker.advance``) plus the since-rollup
-delta resolution (``delta_linker.delta_link_context``) produce a
-LinkContext that is BIT-IDENTICAL to ``linker.link_context`` run from
-scratch over the full ring — at every instant, under arbitrary
-ingest/flush/rollup interleavings, with sampling flipping ``r_keep``
-under the resolver's feet, and across crash-resume (the resumed ctx
-leaves must put the reborn process on the exact same answers).
+``rollup_step`` advances the persistent ctx leaves with one sort of the
+join union (``delta_linker.advance``) and emits its links from the tree
+that advance resolves. That tree and that LinkContext must be
+BIT-IDENTICAL to ``linker.link_context`` run from scratch over the same
+ring — under arbitrary ingest/flush/rollup interleavings, with sampling
+flipping ``r_keep`` under the resolver's feet, and across crash-resume
+(the resumed ctx leaves must put the reborn process on the exact same
+answers). A fresh dependency read IS the oracle
+(``ingest.fresh_link_context``); what is held of it here is its shape
+on the chip (one sort, no search) and that the aggregator's fresh and
+cached reads agree with it.
 
-Bit-identity (not "same edges") is the contract because the delta
-formulation's exactness argument is structural — the age partition
-doomed/safe/delta covers every lane exactly once and the candidate
-pick mirrors the oracle's first-inserted preference chain — and any
-crack in that argument shows up first as a single divergent parent
-lane, long before it corrupts an aggregate.
+Bit-identity (not "same edges") is the contract because any crack in
+the advance's candidate choice shows up first as a single divergent
+parent lane, long before it corrupts an aggregate.
 """
 
 from __future__ import annotations
@@ -30,6 +30,7 @@ import pytest
 
 from tests.fixtures import TODAY_US, lots_of_spans
 from zipkin_tpu import faults
+from zipkin_tpu.model.json_v2 import encode_span_list
 from zipkin_tpu.model.span import Endpoint, Kind, Span
 from zipkin_tpu.ops import delta_linker, linker
 from zipkin_tpu.storage.tpu import TpuStorage
@@ -40,17 +41,23 @@ from zipkin_tpu.tpu.state import AggConfig, init_state
 
 @functools.lru_cache(maxsize=None)
 def _ctx_programs(config):
-    """One compile per config — a fresh jit per assert would recompile."""
+    """One compile per config — a fresh jit per assert would recompile.
+    Two programs: inside one jit XLA would merge the oracle's sort with
+    the advance's and compare a value with itself."""
     return (
-        jax.jit(lambda s: ing.fresh_link_context(config, s)),
+        jax.jit(lambda s: delta_linker.advance(
+            ing.ring_link_input(s), ing.ctx_struct(s), config.rollup_segment
+        )[4]),
         jax.jit(lambda s: linker.link_context(ing.ring_link_input(s))),
     )
 
 
 def assert_ctx_identical(config, state, where=""):
-    """fresh (persistent ctx + delta) == from-scratch oracle, leaf-for-leaf."""
-    fresh, oracle = _ctx_programs(config)
-    got = fresh(state)
+    """The context the roll-up would emit from at this instant (the
+    advance over the state's ctx leaves) == the from-scratch oracle,
+    leaf-for-leaf."""
+    advanced, oracle = _ctx_programs(config)
+    got = advanced(state)
     want = oracle(state)
     for name, g, w in zip(got._fields, got, want):
         np.testing.assert_array_equal(
@@ -109,9 +116,9 @@ def test_fuzz_interleavings_bit_identical(seed, ring_pow):
 
 
 def test_empty_ring_and_first_batches():
-    """init_state's ctx leaves are a valid advance fixpoint: the very
-    first fresh read (delta over an all-invalid ring) matches the
-    oracle, as does every read before the first rollup ever runs."""
+    """init_state's ctx leaves are a valid advance input: the advance
+    over the pristine ring matches the oracle, as does every advance
+    before the first rollup ever runs."""
     cfg = AggConfig(
         max_services=64, max_keys=256, hll_precision=9,
         digest_centroids=32, ring_capacity=1 << 7,
@@ -198,9 +205,8 @@ class Advance(NamedTuple):
                 ids=lambda p: f"seed{p[0]}-ring{1 << p[1]}")
 def trajectory(request):
     """Four ring wraps of tangled spans in small batches, rolled up at
-    the host cadence and twice on a part-written segment, with a fresh
-    read compared after every batch. The first advances see a ring that
-    still has never-written (invalid) lanes."""
+    the host cadence and twice on a part-written segment. The first
+    advances see a ring that still has never-written (invalid) lanes."""
     seed, ring_pow = request.param
     cfg = AggConfig(
         max_services=64, max_keys=256, hll_precision=9,
@@ -213,7 +219,6 @@ def trajectory(request):
     )
     step = jax.jit(lambda s, b: ing.ingest_step(cfg, s, b))
     rollup = jax.jit(lambda s: ing.rollup_step(cfg, s))
-    fresh, oracle_ctx = _ctx_programs(cfg)
 
     advance = jax.jit(lambda s: delta_linker.advance(
         ing.ring_link_input(s), ing.ctx_struct(s), seg
@@ -257,7 +262,7 @@ def trajectory(request):
             since = 0
         state = step(state, sub)
         since += lanes
-        reads.append((lo, since, host(fresh(state)), host(oracle_ctx(state))))
+        reads.append((lo, since))
     return cfg, advances, reads
 
 
@@ -265,7 +270,7 @@ def test_advance_trajectory_is_long_enough(trajectory):
     cfg, advances, reads = trajectory
     assert len(advances) >= 8  # four wraps, two roll-ups a wrap
     assert any(0 < a.since < cfg.rollup_segment - 24 for a in advances)
-    assert max(since for _, since, _, _ in reads) == cfg.rollup_segment
+    assert max(since for _, since in reads) == cfg.rollup_segment
 
 
 def test_advance_tree_is_the_oracles(trajectory):
@@ -353,16 +358,6 @@ def test_advance_is_deterministic_and_is_what_rollup_step_stores(trajectory):
                 g, stored, err_msg=f"ctx.{name} is not the state's, roll-up {k}")
 
 
-def test_fresh_reads_after_each_advance_are_the_oracles(trajectory):
-    """After any number of further writes up to rollup_segment."""
-    _, _, reads = trajectory
-    for lo, since, got, want in reads:
-        for name, g, w in zip(got._fields, got, want):
-            np.testing.assert_array_equal(
-                g, w, err_msg=f"LinkContext.{name} at span offset {lo}, "
-                f"{since} lanes after the advance")
-
-
 # ----------------------------------------------------------------------
 # structural fence: what would otherwise show only on the chip
 # ----------------------------------------------------------------------
@@ -405,17 +400,22 @@ def test_rollup_step_has_one_sort_and_one_loop():
     assert names.count("while") == 1 and names.count("scan") == 0
 
 
-def test_fresh_read_sorts_only_its_delta():
-    prims = _primitives(
-        jax.make_jaxpr(lambda s: ing.fresh_link_context(FENCE_CFG, s))(
-            init_state(FENCE_CFG)
-        ).jaxpr, [],
-    )
-    widths = [e.invars[0].aval.shape[0] for name, e in prims if name == "sort"]
-    assert widths and max(widths) <= 2 * FENCE_CFG.rollup_segment
+@pytest.mark.parametrize("cfg", [FENCE_CFG, AggConfig()],
+                         ids=["ring256", "server-default"])
+def test_fresh_read_has_one_sort_and_no_search(cfg):
+    """The fresh read resolves from scratch: ONE stable 4-key sort of
+    the 2R-lane union, ONE loop (the chase's while). A binary search of
+    stored keys traces as ``scan`` here and held every sender 0.67 s a
+    read on the chip (PERF.md section 6), at the server's default size
+    as at a small one."""
+    state = jax.eval_shape(lambda: init_state(cfg))
+    prims = _primitives(jax.make_jaxpr(ing.fresh_link_context)(state).jaxpr, [])
     names = [name for name, _ in prims]
-    # the two searches of the stored keys, and the chase
-    assert names.count("scan") == 2 and names.count("while") == 1
+    assert names.count("sort") == 1
+    (sort,) = [e for name, e in prims if name == "sort"]
+    assert sort.invars[0].aval.shape == (2 * cfg.ring_capacity,)
+    assert sort.params["is_stable"] and sort.params["num_keys"] == 4
+    assert names.count("scan") == 0 and names.count("while") == 1
 
 
 # ----------------------------------------------------------------------
@@ -429,9 +429,9 @@ STORE_CFG = AggConfig(
 )
 
 
-def make_store(tmp_path, tag=""):
+def make_store(tmp_path, tag="", num_devices=2):
     return TpuStorage(
-        config=STORE_CFG, num_devices=2, batch_size=512,
+        config=STORE_CFG, num_devices=num_devices, batch_size=512,
         checkpoint_dir=str(tmp_path / f"ckpt{tag}"),
         wal_dir=str(tmp_path / f"wal{tag}"),
         archive_dir=str(tmp_path / f"archive{tag}"),
@@ -489,6 +489,63 @@ def test_store_cadence_with_sampling_active(tmp_path):
         store.close()
 
 
+def oracle_edges(agg, ts_lo, ts_hi):
+    """(flat_index, calls, errors) of every nonzero cell of the link
+    matrix summed over the shards, each shard's links computed by
+    ``dependency_links`` with no ctx (the from-scratch resolve)."""
+    clone, _, _ = agg.state_clone()
+    calls = errors = 0
+    for k in range(np.asarray(clone.r_valid).shape[0]):
+        shard = type(clone)(*(np.asarray(leaf)[k] for leaf in clone))
+        c, e = ing.dependency_links(
+            STORE_CFG, shard, np.uint32(ts_lo), np.uint32(ts_hi)
+        )
+        calls, errors = calls + np.asarray(c), errors + np.asarray(e)
+    idx = np.flatnonzero(calls)
+    return idx, calls.reshape(-1)[idx], errors.reshape(-1)[idx]
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_fresh_cached_and_from_scratch_edges_agree_across_rollup(
+    tmp_path, devices
+):
+    """The aggregator's fresh read (``spmd_edges_fresh``, which primes
+    the per-version ctx cache), its cached follow-up (``spmd_edges`` on
+    that ctx) and ``dependency_links`` with no ctx, summed over the
+    shards, give the same edges, before a roll-up and after it (the
+    rolled half then answers from the buckets, the rest from the live
+    ring)."""
+    lo, hi = 0, 0xFFFFFFFF
+    store = make_store(tmp_path, num_devices=devices)
+    try:
+        agg = store.agg
+        reads = []
+        for b in range(3):
+            store.ingest_json_fast(
+                encode_span_list(tangled_spans(700, seed=40 + b))
+            )
+            if b == 2:
+                agg.rollup_now()
+            before = agg.read_stats["ctx_reads"]
+            fresh = agg.dependency_edges(lo, hi)
+            assert agg._ctx_cache[0] == agg.write_version
+            cached = agg.dependency_edges(lo, hi)
+            assert agg.read_stats["ctx_reads"] == before + 2
+            want = oracle_edges(agg, lo, hi)
+            assert len(want[0]) > 0
+            for got in (fresh, cached):
+                have = got[1] > 0
+                for g, w in zip(got, want):
+                    np.testing.assert_array_equal(
+                        np.asarray(g)[have], w, err_msg=f"after batch {b}")
+            reads.append(want)
+        # the roll-up between the last two reads moved no edge: the
+        # batch before it added its own, and nothing else changed
+        assert reads[2][1].sum() > reads[1][1].sum()
+    finally:
+        store.close()
+
+
 # ----------------------------------------------------------------------
 # crash-resume: resumed ctx leaves are bit-identical
 # ----------------------------------------------------------------------
@@ -497,8 +554,8 @@ def test_store_cadence_with_sampling_active(tmp_path):
 def test_crash_mid_wal_append_resumes_identical_ctx(tmp_path):
     """Kill the process mid-WAL-append (the PR-3 fault registry's
     nastiest instant) and reboot from disk: WAL replay re-runs the same
-    fused steps, so the reborn ctx leaves — and the fresh reads built on
-    them — must be bit-identical to the oracle AND to a second pristine
+    fused steps, so the reborn ctx leaves — and the context the advance
+    builds on them — must be bit-identical to the oracle AND to a second pristine
     boot from the same disk state."""
     victim = make_store(tmp_path)
     victim.ingest_json_fast(payload(900, base=1))

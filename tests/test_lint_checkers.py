@@ -354,50 +354,55 @@ def test_zt06_exempts_benchmarks_and_tests(tmp_path):
         assert rules(lint(tmp_path, ZT06_POSITIVE, name=name)) == []
 
 
-# -- ZT07: fresh-read ring sorts ----------------------------------------
+# -- ZT07: fresh-read searches ------------------------------------------
 
 
 ZT07_POSITIVE = """
     import jax
     import jax.numpy as jnp
 
-    def _resolve(keys):
-        return jax.lax.sort(keys, num_keys=4)
+    def _lower_bound(tbl, q):
+        def body(_, lohi):
+            return lohi
+        return jax.lax.fori_loop(0, 20, body, (q, q))
 
     def spmd_edges_fresh(state, ts_lo, ts_hi):
-        order = _resolve(state.ring_keys)
-        return order
+        pos = _lower_bound(state.ctx_keys, state.ring_keys)
+        return pos
 """
 
 
-def test_zt07_flags_sort_reachable_from_fresh_entrypoint(tmp_path):
+def test_zt07_flags_search_reachable_from_fresh_entrypoint(tmp_path):
     assert_rule_owned(tmp_path, ZT07_POSITIVE, "ZT07")
 
 
-def test_zt07_flags_from_scratch_rebuilder_call(tmp_path):
+def test_zt07_flags_scan_one_import_away(tmp_path):
+    # the walk follows the call into another function of the fresh path
     result = lint(
         tmp_path,
         """
-        import jax
-        from zipkin_tpu.ops import linker
+        from jax import lax
 
-        def fresh_link_context(config, state):
-            return linker.link_context(state.ring)
+        def _runs(keys):
+            return lax.scan(lambda c, k: (c, k), 0, keys)
+
+        def fresh_link_context(state):
+            return _runs(state.ring_keys)
         """,
     )
     assert "ZT07" in rules(result)
 
 
-def test_zt07_ignores_sorts_on_the_rollup_path(tmp_path):
-    # the same sort outside the fresh-read surface (rollup cadence /
-    # oracle) is the design, not a violation
+def test_zt07_ignores_searches_on_the_rollup_path(tmp_path):
+    # the same loop outside the fresh-read surface (roll-up cadence)
+    # holds no sender, and is not this rule's
     result = lint(
         tmp_path,
         """
         import jax
 
         def advance(state, seg):
-            return jax.lax.sort(state.ring_keys, num_keys=4)
+            return jax.lax.fori_loop(0, 20, lambda i, c: c, state.ring_keys)
 
         def rollup_step(config, state):
             return advance(state, config.rollup_segment)
@@ -406,29 +411,31 @@ def test_zt07_ignores_sorts_on_the_rollup_path(tmp_path):
     assert rules(result) == []
 
 
-def test_zt07_ignores_cumsum_on_fresh_path(tmp_path):
-    # prefix sums are the delta formulation's own workhorse: O(n)
-    # vectorized, not the O(n log n) comparison sort the rule fences
+def test_zt07_ignores_sort_and_cumsum_on_fresh_path(tmp_path):
+    # the from-scratch resolve: one sort of the union, prefix sums for
+    # the run ids, the chase's convergence-bounded while_loop
     result = lint(
         tmp_path,
         """
         import jax
         import jax.numpy as jnp
 
-        def delta_resolve(x, cs, seg):
-            return jnp.cumsum(cs.run_starts)
+        def fresh_link_context(state):
+            keys = jax.lax.sort(state.ring_keys, num_keys=4)
+            rid = jnp.cumsum(keys[0] != 0)
+            return jax.lax.while_loop(lambda c: c < 0, lambda c: c, rid)
         """,
     )
     assert rules(result) == []
 
 
-def test_zt07_pragma_with_delta_bound_suppresses(tmp_path):
+def test_zt07_pragma_with_bound_suppresses(tmp_path):
     result = lint(
         tmp_path,
         ZT07_POSITIVE.replace(
-            "return jax.lax.sort(keys, num_keys=4)",
-            "return jax.lax.sort(keys, num_keys=4)"
-            "  # zt-lint: disable=ZT07 — sorts only the 2*seg delta lanes",
+            "return jax.lax.fori_loop(0, 20, body, (q, q))",
+            "return jax.lax.fori_loop(0, 20, body, (q, q))"
+            "  # zt-lint: disable=ZT07 — 20 passes over 4,096 queries",
         ),
     )
     assert rules(result) == []

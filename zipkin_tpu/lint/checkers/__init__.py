@@ -15,10 +15,9 @@ Rule catalog (grounded in real past regressions — see ARCHITECTURE.md
   but lock-free in another.
 - ZT05 donation misuse: a donated argument read after the donating call.
 - ZT06 blocking sync: ``block_until_ready`` on serving paths.
-- ZT07 fresh-read ring sorts: sort/scan-family ops (or calls back into
-  the from-scratch ctx rebuilders) reachable from fresh-read
-  entrypoints — only the since-rollup delta segment may be sorted at
-  query time.
+- ZT07 fresh-read searches: ``fori_loop``/``lax.scan`` (a binary
+  search's shape) reachable from fresh-read entrypoints — the read holds
+  every sender while it runs, and resolves by one sort instead.
 - ZT08 obs stage discipline: ``obs.record`` reachable from
   device-traced code (host instrumentation runs once at trace time),
   or a stage argument outside the closed catalogue in

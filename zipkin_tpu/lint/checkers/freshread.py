@@ -1,38 +1,28 @@
-"""ZT07 — full-ring sorts on the fresh-read path.
+"""ZT07 — searches on the fresh-read path.
 
-ISSUE 5's tentpole moved the O(R log R) link-context rebuild (a 4-key
-``lax.sort`` over 2^19 union lanes — 29.6 ms of the 41.3 ms r5 fresh
-read) off the query path: the sorted union order is maintained
-incrementally at rollup cadence, and a fresh read may sort only the
-since-rollup DELTA segment (``ops/delta_linker.py``). This rule is the
-regression fence: any sort/scan-family op — or a call back into the
-from-scratch rebuilders — reachable from a fresh-read entrypoint is a
-reintroduction of the full-ring cost and fails tier-1
-(tests/test_lint_clean.py).
+A fresh dependency read runs under the aggregator lock that every POST
+needs, so its device time is every sender's stall. On the chip a
+vectorized binary search — a ``fori_loop`` of ~20 passes of gathers
+over 2^19 stored keys — is the slow shape: two of them cost the
+since-rollup delta read 0.67 s of its 0.87-1.26 s a call, where the
+from-scratch resolve's one sort of the whole join union costs some
+30 ms (TPU v5e, PERF.md), and four of them had cost the roll-up's
+advance 2.2 s. So the fresh read resolves from scratch
+(``tpu/ingest.py:fresh_link_context``), and this rule is the fence
+against a search coming back: a ``fori_loop`` or ``lax.scan`` reachable
+from a fresh-read entrypoint fails tier-1 (tests/test_lint_clean.py).
+Sorts, prefix sums, the run-min ladder and the ancestor chase's
+convergence-bounded ``while_loop`` are the shapes the chip runs fast
+and are not flagged.
 
 Mechanics: functions named in ``FRESH_READ_ENTRYPOINTS`` — wherever
 they live — seed a walk over the WHOLE-PROGRAM call graph (qualified-
 name resolution; conservative fallback edges descend into same-module
 defs and uniquely-named imported methods, over-approximating rather
-than missing a helper), so a sort can no longer hide one import away.
-Inside reachable functions, in whatever module the walk lands, two
-shapes are flagged:
-
-1. sort/scan-family calls: ``lax.sort``, ``jnp.sort``, ``jnp.argsort``,
-   ``jnp.lexsort``, ``lax.associative_scan``, ``lax.scan``.
-   ``jnp.cumsum`` is deliberately NOT in the set: prefix sums are the
-   delta formulation's own workhorse (compaction counting, run-id
-   assignment) and are O(n) elementwise-cheap vectorized ops — the
-   hazard this rule fences is the O(n log n) comparison sort and the
-   sequential carry loop, not parallel prefix.
-2. calls to the from-scratch rebuilders ``link_context`` /
-   ``resolve_parents`` (ops/linker.py): correct answers, wrong tier —
-   they are the rollup/oracle path.
-
-The ONE legitimate sort on the fresh path — the delta-segment sort in
-``delta_linker._resolve_core`` — carries a ZT07 pragma whose reason
-states the bound (``sorts only the 2·Δ delta-segment lanes``); the
-pragma-with-reason mechanism (ZT00) keeps that claim reviewable.
+than missing a helper), so a search can no longer hide one import away.
+A loop that belongs there carries a ZT07 pragma whose reason states its
+bound; the pragma-with-reason mechanism (ZT00) keeps that claim
+reviewable.
 
 ISSUE 15 added a second fenced surface with the same failure shape at a
 different tier: windowed sketch queries (``[lookback, endTs]`` on the
@@ -44,9 +34,9 @@ that answers an uncovered window by rescanning the span archive
 query, exactly the cost the tier exists to avoid; uncovered epochs are
 reported as coverage gaps instead). That walk is UNGATED on jax
 imports: the windowed routing layer is pure host code and must stay
-fenced even if it moves out of a jax-importing module. (The sort fence
-gates on the ROOT's module importing jax — the hazard is a device
-sort/scan, which a jax-free entrypoint module cannot seed.)
+fenced even if it moves out of a jax-importing module. (The search
+fence gates on the ROOT's module importing jax — the hazard is a device
+loop, which a jax-free entrypoint module cannot seed.)
 """
 
 from __future__ import annotations
@@ -65,18 +55,12 @@ _FUNC_KINDS = (ast.FunctionDef, ast.AsyncFunctionDef)
 FRESH_READ_ENTRYPOINTS = {
     "spmd_link_ctx",        # parallel/sharded.py: ctx-only program
     "spmd_edges_fresh",     # parallel/sharded.py: fused ctx+edges program
-    "fresh_link_context",   # tpu/ingest.py: delta-read entrypoint
-    "delta_link_context",   # ops/delta_linker.py: resolve + chase + rules
-    "delta_resolve",        # ops/delta_linker.py: resolve only
+    "fresh_link_context",   # tpu/ingest.py: the read's resolve
 }
 
-# O(n log n) sorts and sequential-carry scans; jnp.cumsum is deliberately
-# absent (see module docstring)
-SORT_SCAN_ATTRS = {"sort", "argsort", "lexsort", "associative_scan", "scan"}
-SORT_SCAN_ROOTS = {"jax", "jnp", "lax"}
-
-# the from-scratch oracle surface (ops/linker.py)
-FULL_REBUILDERS = {"link_context", "resolve_parents"}
+# fixed-count carry loops: a binary search's shape (see module docstring)
+SEARCH_LOOP_ATTRS = {"fori_loop", "scan"}
+SEARCH_LOOP_ROOTS = {"jax", "lax"}
 
 # windowed sketch-tier entrypoints (tpu/store.py, ISSUE 15): queries
 # carrying a [lookback, endTs] range answer from merged time-bucket
@@ -108,13 +92,13 @@ class FreshReadRingSort(Checker):
     severity = "error"
     name = "fresh-read-ring-sort"
     doc = (
-        "sort/scan ops or from-scratch ctx rebuilds reachable from "
+        "fori_loop/scan (a binary search's shape) reachable from "
         "fresh-read entrypoints"
     )
     hint = (
-        "fresh reads may only sort the since-rollup delta segment "
-        "(ops/delta_linker.py); move full-ring work to rollup cadence, "
-        "or suppress with a reason stating the delta-size bound"
+        "resolve by one sort of the join union and contiguous work over "
+        "it (linker.link_context), or suppress with a reason stating the "
+        "loop's bound"
     )
     whole_program = True
 
@@ -128,8 +112,8 @@ class FreshReadRingSort(Checker):
                 qual = program.qual_of(fn)
                 if qual is None:
                     continue
-                # sort fence gates on the ROOT's module importing jax:
-                # the hazard is a device sort/scan, which a jax-free
+                # the search fence gates on the ROOT's module importing
+                # jax: the hazard is a device loop, which a jax-free
                 # entrypoint module cannot seed
                 if fn.name in FRESH_READ_ENTRYPOINTS and jax_gated:
                     fresh_roots.append(qual)
@@ -157,25 +141,16 @@ class FreshReadRingSort(Checker):
             name = _callee_name(node.func)
             if (
                 isinstance(node.func, ast.Attribute)
-                and name in SORT_SCAN_ATTRS
-                and _root_name(node.func) in SORT_SCAN_ROOTS
+                and name in SEARCH_LOOP_ATTRS
+                and _root_name(node.func) in SEARCH_LOOP_ROOTS
             ):
                 where = "" if fn.name == root else f" (via {fn.name}())"
                 yield self.found(
                     module,
                     node,
                     f"{_root_name(node.func)}.{name} reachable from "
-                    f"fresh-read entrypoint {root}(){where} — fresh reads "
-                    "must not pay full-ring sort/scan cost",
-                )
-            elif name in FULL_REBUILDERS and fn.name not in FULL_REBUILDERS:
-                where = "" if fn.name == root else f" (via {fn.name}())"
-                yield self.found(
-                    module,
-                    node,
-                    f"from-scratch rebuilder {name}() called from "
-                    f"fresh-read entrypoint {root}(){where} — use the "
-                    "incremental delta formulation",
+                    f"fresh-read entrypoint {root}(){where} — a search "
+                    "loop holds every sender for its passes",
                 )
 
     def _scan_windowed(self, module: Module, fn: ast.AST, root: str):

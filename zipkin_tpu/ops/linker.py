@@ -209,7 +209,7 @@ class SortedUnion(NamedTuple):
     """The 2n-lane join union after its ONE sort: everything downstream
     (run ids, first-wins candidates, the preference chain, and the
     persistent ctx that :func:`zipkin_tpu.ops.delta_linker.advance`
-    keeps for the fresh reads) is contiguous work over these lanes."""
+    keeps at roll-up cadence) is contiguous work over these lanes."""
 
     keys: Tuple[jnp.ndarray, ...]  # 4 x u32 [2n]: trace_h, id0, id1, svc
     sh: jnp.ndarray     # i32 [2n] insertion rank of a shared table lane, else 2n
@@ -251,7 +251,6 @@ def sort_union(x: LinkInput) -> SortedUnion:
         [flags, flags]
     )
 
-    # zt-lint: disable=ZT07 — fresh entrypoints reach this only through dependency_links' ctx=None fallback, which they never take (they always pass the delta ctx from fresh_link_context); the full-ring sort runs at rollup cadence / cold rebuilds only
     *keys, pay = jax.lax.sort(
         tuple(id_lanes) + (svc_lane, payload), num_keys=4
     )
@@ -483,11 +482,11 @@ def link_context(x: LinkInput) -> LinkContext:
     full traces whose span timestamps intersect the window, SURVEY.md
     §3.5).
 
-    This is the FROM-SCRATCH formulation (full union sort + run-min
-    ladder): the oracle the incremental delta path
-    (ops/delta_linker.py) must match bit-for-bit, and the reference
-    every parity test fuzzes against. Production fresh reads ride the
-    delta formulation; this one remains the ground truth.
+    This is the FROM-SCRATCH formulation (one union sort + the run-min
+    ladder + the chase), and production fresh reads run it as it is
+    (``tpu/ingest.py:fresh_link_context``). The roll-up's advance
+    (ops/delta_linker.py) must match it bit-for-bit, and every parity
+    test fuzzes against it.
     """
     parent, has_child = resolve_parents(x)
     anc, root_ok = chase_ancestors(parent, jnp.where(x.valid, x.kind, 0))

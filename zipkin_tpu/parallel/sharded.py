@@ -149,16 +149,11 @@ def _compiled_programs(config: AggConfig, mesh: Mesh):
     }
 
     def spmd_link_ctx(state: AggState):
-        """The window-independent half of a dependency query, via the
-        INCREMENTAL delta formulation (ops/delta_linker.py): persistent
-        ctx rebuilt by the rollup's one full-union sort + a read-time
-        sort of only the since-rollup delta segment — bit-identical to
-        the from-scratch linker.link_context oracle (fuzzed in
-        tests/test_incremental_ctx)
-        without the full-ring union sort that cost ~29.6 ms of the
-        41.3 ms r5 fresh read."""
+        """The window-independent half of a dependency query, resolved
+        from scratch over the shard's ring (ing.fresh_link_context: one
+        sort of the join union, no search of stored keys)."""
         s = jax.tree_util.tree_map(lambda a: a[0], state)
-        ctx = ing.fresh_link_context(config, s)
+        ctx = ing.fresh_link_context(s)
         return jax.tree_util.tree_map(lambda a: a[None], ctx)
 
     link_ctx = jax.jit(
@@ -381,17 +376,16 @@ def _compiled_programs(config: AggConfig, mesh: Mesh):
 
     def spmd_edges_fresh(ctxless_state: AggState, ts_lo, ts_hi):
         """The FRESH dependency read: first query after a write. One
-        dispatch computes the link context — via the incremental DELTA
-        formulation: persistent ctx + a sort of only the since-rollup
-        segment (ops/delta_linker.py); the full-ring sort runs at rollup
-        cadence, never here — plus the windowed top-E edges, and
-        returns both so the host caches the
-        ctx for follow-up windows. This program GATES the <50 ms query
-        SLO with no amortized exclusions (VERDICT r3 order 1): r3 paid
-        145.8 ms + 6.8 ms in two dispatches, r5's from-scratch fused
-        read 41.3 ms, the delta read only the since-rollup segment."""
+        dispatch computes the link context from scratch
+        (ing.fresh_link_context: one sort of the 2R-lane join union, the
+        run-min ladder, the chase) plus the windowed top-E edges, and
+        returns both so the host caches the ctx for follow-up windows.
+        It runs under the aggregator lock that every POST needs, so its
+        device time is the senders' stall: the since-rollup delta read
+        it replaced, two binary searches of the stored keys, held them
+        0.87-1.26 s a call on a TPU v5e (PERF.md)."""
         s = jax.tree_util.tree_map(lambda a: a[0], ctxless_state)
-        c = ing.fresh_link_context(config, s)
+        c = ing.fresh_link_context(s)
         calls, errors = ing.dependency_links(config, s, ts_lo, ts_hi, ctx=c)
         ctx_out = jax.tree_util.tree_map(lambda a: a[None], c)
         return ctx_out, _edge_topk(calls, errors)

@@ -359,14 +359,13 @@ def ctx_struct(state: AggState) -> delta_linker.CtxStruct:
     )
 
 
-def fresh_link_context(config: AggConfig, state: AggState) -> linker.LinkContext:
-    """The fresh-read link context via the incremental delta formulation:
-    persistent ctx + since-advance delta segment, bit-identical to
-    ``linker.link_context(ring_link_input(state))`` (the from-scratch
-    oracle) but without any full-ring sort (the rollup did that one)."""
-    return delta_linker.delta_link_context(
-        ring_link_input(state), ctx_struct(state), config.rollup_segment
-    )
+def fresh_link_context(state: AggState) -> linker.LinkContext:
+    """The fresh-read link context, resolved from scratch over the ring:
+    one stable sort of the 2R-lane join union, the three-channel run-min
+    ladder, the ancestor chase and the rules (``linker.link_context``).
+    The persistent ``ctx_*`` leaves are the roll-up's and are not read
+    here."""
+    return linker.link_context(ring_link_input(state))
 
 
 def rollup_step(config: AggConfig, state: AggState) -> AggState:
@@ -384,11 +383,9 @@ def rollup_step(config: AggConfig, state: AggState) -> AggState:
     ``config.rollup_segment`` (see ShardedAggregator.ingest), so no valid
     span is ever overwritten without its links being preserved.
 
-    ISSUE 5: this is also where the persistent incremental link ctx
-    ADVANCES — one sort of the whole join union (delta_linker.advance)
-    gives the rollup's emit context and the rebuilt ctx order alike, and
-    the refreshed ctx is what makes the next fresh read pay only its
-    own since-rollup delta.
+    This is also where the persistent link ctx ADVANCES — one sort of
+    the whole join union (delta_linker.advance) gives the rollup's emit
+    context and the rebuilt ctx leaves alike.
     """
     x = ring_link_input(state)
     # x.seq is age-since-cursor: the lanes the cursor will overwrite next
@@ -483,8 +480,7 @@ def dependency_links(
     ring-sort half — the aggregator caches one per state version.
     """
     if ctx is None:
-        # zt-lint: disable=ZT07 — dead branch on the fresh path: spmd_edges_fresh always passes the delta ctx (fresh_link_context); this fallback serves warm-read/test callers where the full rebuild is the point
-        ctx = linker.link_context(ring_link_input(state))
+        ctx = fresh_link_context(state)
     in_window = (state.r_ts_min >= ts_lo) & (state.r_ts_min <= ts_hi)
     calls, errors = linker.emit_links(
         ctx, state.r_valid & ~state.r_rolled & in_window, config.max_services
@@ -559,7 +555,7 @@ def tt_sketches(
     all_c = jnp.moveaxis(dm, 0, 1).reshape(k, w_tt * cw, 2)
     digest = tdigest.row_merge(jnp.zeros((k, cw, 2), jnp.float32), all_c)
     if ctx is None:
-        ctx = fresh_link_context(config, state)
+        ctx = fresh_link_context(state)
     g = jnp.uint32(config.time_bucket_minutes)
     ep_lane = (state.r_ts_min // g).astype(jnp.int32)
     in_w = (ep_lane >= lo_ep) & (ep_lane <= hi_ep)

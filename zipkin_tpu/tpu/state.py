@@ -198,8 +198,8 @@ class AggState(NamedTuple):
     # sorted join-union order over the ring, its run decomposition, the
     # per-run first-wins candidates restricted to lanes that cannot be
     # overwritten before the next advance, and the resolved tree at the
-    # last advance. Advanced at rollup cadence; a fresh dependency read
-    # pays only the since-advance delta segment against these.
+    # last advance. Advanced at rollup cadence. No read uses them: a
+    # fresh dependency read resolves from scratch (ingest.fresh_link_context).
     ctx_order: jnp.ndarray  # i32 [2R] union index per sorted position
     ctx_keys: jnp.ndarray  # u32 [4, 2R] sort-key snapshot per position
     ctx_rid_c: jnp.ndarray  # i32 [2R] coarse run id (1-based)
