@@ -1,14 +1,15 @@
 """Native C columnar parser: field parity against the Python codec, the
 fallback contract, and the fast ingest path (SURVEY.md §7 hard-part 1)."""
 
+import dataclasses
 import json
 
 import numpy as np
 import pytest
 
-from tests.fixtures import TRACE, lots_of_spans
+from tests.fixtures import TODAY, TODAY_US, TRACE, lots_of_spans
 from zipkin_tpu import native
-from zipkin_tpu.model import json_v2
+from zipkin_tpu.model import json_v1, json_v2, proto3
 from zipkin_tpu.tpu.columnar import KIND_TO_ID, Vocab, pack_parsed, pack_spans
 
 pytestmark = pytest.mark.skipif(
@@ -200,3 +201,201 @@ class TestMixedPathCoherence:
         rows = store.latency_quantiles([0.5], use_digest=False)
         svcs = {r["serviceName"] for r in rows}
         assert {"svc00", "svc08"} <= svcs  # both paths' data queryable
+
+
+# -- where the fast path decides the JSON dialect ---------------------------
+#
+# The native tokenizer tells v1 from v2 in its own pass (span_json.c,
+# ERR_V1); nothing on the fast path scans the body for v1 markers. Each
+# case goes through Collector.accept_spans_bytes with fast ingest, and must
+# leave the store as the object path leaves it when fed the spans the
+# payload's own dialect decodes to.
+
+def _fast_store():
+    from zipkin_tpu.tpu.state import AggConfig
+    from zipkin_tpu.tpu.store import TpuStorage
+
+    cfg = AggConfig(max_services=64, max_keys=256, hll_precision=9,
+                    digest_centroids=16, digest_buffer=4096,
+                    ring_capacity=1 << 13)
+    # every trace archived, so the name lists see all of both paths' spans
+    return TpuStorage(config=cfg, pad_to_multiple=256, fast_archive_sample=1)
+
+
+def _v1_body(n, *, binary=(), endpoint_annotations=()):
+    """n v1 spans in traces of three; the spans whose index is in
+    ``binary`` carry a local-component binary annotation, those in
+    ``endpoint_annotations`` server annotations with an endpoint, the rest
+    neither (and so read alike in either dialect)."""
+    out = []
+    for i in range(n):
+        ep = {"serviceName": f"svc{i % 3}", "ipv4": f"10.0.0.{i % 3 + 1}"}
+        ts = TODAY_US + i * 1000
+        span = {"traceId": f"{0x100 + i // 3:016x}", "id": f"{i + 1:016x}",
+                "name": f"op{i % 4}", "timestamp": ts, "duration": 500 + i}
+        if i % 3:
+            span["parentId"] = f"{i:016x}"
+        if i in endpoint_annotations:
+            span["annotations"] = [
+                {"timestamp": ts, "value": "sr", "endpoint": ep},
+                {"timestamp": ts + 500 + i, "value": "ss", "endpoint": ep},
+            ]
+        if i in binary:
+            span["binaryAnnotations"] = [
+                {"key": "lc", "value": "", "endpoint": ep}]
+        out.append(span)
+    return json.dumps(out).encode()
+
+
+def _v2_annotated(tags=None):
+    from zipkin_tpu.model.span import Span
+
+    return [
+        Span.create(
+            s.trace_id, s.id, parent_id=s.parent_id, kind=s.kind,
+            name=s.name, timestamp=s.timestamp, duration=s.duration,
+            local_endpoint=s.local_endpoint,
+            remote_endpoint=s.remote_endpoint,
+            annotations=[(s.timestamp + 1, "ws"), (s.timestamp + 2, "wr")],
+            tags={**s.tags, **(tags or {})},
+        )
+        for s in lots_of_spans(240, seed=43)
+    ]
+
+
+def _proto3_0x5b():
+    """A ListOfSpans whose first span is 0x5B ('[') bytes long."""
+    from zipkin_tpu.model.span import Endpoint, Span
+
+    base = dict(trace_id="000000000000000a", timestamp=1_000_000,
+                duration=10, local_endpoint=Endpoint.create("svc"))
+    for pad in range(1, 60):
+        s = Span.create(id="000000000000000b", name="n" * pad, **base)
+        if len(proto3.encode_span(s)) == 0x5B:
+            return proto3.encode_span_list([s])
+    raise AssertionError("could not synthesize an 0x5B-byte span")
+
+
+def _v2_with_tag(key):
+    spans = lots_of_spans(120, seed=47)
+    spans[7] = dataclasses.replace(spans[7], tags={key: "x"})
+    return json_v2.encode_span_list(spans)
+
+
+def _v2(body):
+    return body, json_v2.decode_span_list, False
+
+
+def _v1(body):
+    return body, json_v1.decode_v1_span_list, True
+
+
+def _p3(body):
+    return body, proto3.decode_span_list, False
+
+
+#: case -> () -> (payload, the decoder of its own dialect, True for JSON v1)
+_DIALECT_CASES = {
+    "v2": lambda: _v2(json_v2.encode_span_list(lots_of_spans(300, seed=41))),
+    "v2_annotations": lambda: _v2(json_v2.encode_span_list(_v2_annotated())),
+    "v2_newline_led": lambda: _v2(
+        b"\n" + json_v2.encode_span_list(lots_of_spans(90, seed=44)) + b"\n"),
+    "v2_space_led": lambda: _v2(
+        b"  \r\n" + json_v2.encode_span_list(lots_of_spans(90, seed=45))),
+    # test_codecs' 0x0A-led JSON: no proto3 frame, so it is the tokenizer's
+    "v2_newline_ambiguity": lambda: _v2(b'\n  [{"traceId":"a","id":"b"}]\n'),
+    "v1_binary_annotations": lambda: _v1(_v1_body(12, binary=range(12))),
+    "v1_endpoint_annotations_first": lambda: _v1(
+        _v1_body(12, endpoint_annotations={0})),
+    "v1_endpoint_annotations_last": lambda: _v1(
+        _v1_body(12, endpoint_annotations={11})),
+    "v1_canonical": lambda: _v1(json_v1.encode_v1_span_list(TRACE)),
+    "proto3": lambda: _p3(
+        proto3.encode_span_list(lots_of_spans(300, seed=46))),
+    "proto3_first_span_0x5b": lambda: _p3(_proto3_0x5b()),
+    # the two bodies the old substring sniff took for v1: they are v2
+    "v2_tag_key_binaryAnnotations": lambda: _v2(
+        _v2_with_tag("binaryAnnotations")),
+    "v2_annotations_and_tag_key_endpoint": lambda: _v2(
+        json_v2.encode_span_list(_v2_annotated(tags={"endpoint": "/api"}))),
+}
+
+
+def _store_view(store, window=(TODAY + 86_400_000, 30 * 86_400_000)):
+    svcs = store.get_service_names().execute()
+    return {
+        "services": svcs,
+        "span_names": {s: store.get_span_names(s).execute() for s in svcs},
+        "links": sorted(
+            (l.parent, l.child, l.call_count, l.error_count)
+            for l in store.get_dependencies(*window).execute()),
+        "spans": store.ingest_counters()["spans"],
+    }
+
+
+@pytest.mark.parametrize("case", list(_DIALECT_CASES))
+def test_fast_path_dialect(case, monkeypatch):
+    from zipkin_tpu.collector.core import Collector, CollectorSampler
+    from zipkin_tpu.collector.core import InMemoryCollectorMetrics
+
+    data, decode, is_v1 = _DIALECT_CASES[case]()
+    spans = decode(data)
+    assert spans
+    # every dependency read fresh: the stores are read twice, seconds apart
+    monkeypatch.setenv("TPU_DEPS_MAX_STALE_MS", "0")
+    fast, ref = _fast_store(), _fast_store()
+    handed = []
+    native_fast = fast.ingest_json_fast
+
+    def spy(body, sampler=None):
+        handed.append(native_fast(body, sampler))
+        return handed[-1]
+
+    fast.ingest_json_fast = spy
+    collector = Collector(
+        fast, sampler=CollectorSampler(1.0),
+        metrics=InMemoryCollectorMetrics().for_transport("http"),
+        fast_ingest=True,
+    )
+    assert collector.accept_spans_bytes(data) == len(spans)
+    assert len(handed) == 1 and (handed[0] is None) == is_v1
+    ref.accept(spans).execute()
+    assert _store_view(fast) == _store_view(ref)
+    assert fast.ingest_counters()["fastIngestDialectFallbacks"] == int(is_v1)
+
+    # the payload after it interns in step, whichever path took the first
+    follow = lots_of_spans(200, seed=48, services=12, span_names=9)
+    assert collector.accept_spans_bytes(
+        json_v2.encode_span_list(follow)) == len(follow)
+    ref.accept(follow).execute()
+    assert _store_view(fast) == _store_view(ref)
+    v = fast.vocab
+    assert fast._nvocab.counts() == (
+        len(v.services) - 1, len(v.span_names) - 1, v.num_keys - 1)
+    assert v.services._names == ref.vocab.services._names
+    assert v.span_names._names == ref.vocab.span_names._names
+    assert v._key_list == ref.vocab._key_list
+    assert fast.ingest_counters()["fastIngestDialectFallbacks"] == int(is_v1)
+
+
+def test_fast_path_never_sniffs_the_body(monkeypatch):
+    """A v2 POST through the fast path calls no body-wide v1 sniff: with
+    the sniff made to raise, the payload is still accepted there."""
+    from zipkin_tpu.collector.core import Collector, CollectorSampler
+    from zipkin_tpu.collector.core import InMemoryCollectorMetrics
+    from zipkin_tpu.model import codec
+
+    def sniff(data):
+        raise AssertionError("the fast path scanned the body for v1")
+
+    monkeypatch.setattr(codec, "_looks_like_v1_json", sniff)
+    store = _fast_store()
+    collector = Collector(
+        store, sampler=CollectorSampler(1.0),
+        metrics=InMemoryCollectorMetrics().for_transport("http"),
+        fast_ingest=True,
+    )
+    spans = lots_of_spans(300, seed=49)
+    assert collector.accept_spans_bytes(
+        json_v2.encode_span_list(spans)) == len(spans)
+    assert store.ingest_counters()["spans"] == len(spans)

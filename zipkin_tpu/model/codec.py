@@ -92,6 +92,19 @@ def _plausible_proto3_frame(data: bytes) -> bool:
 
 def detect(data: bytes) -> Encoding:
     """Sniff the encoding of an ingest payload from its first byte(s)."""
+    enc = detect_framing(data)
+    if enc is Encoding.JSON_V2 and _looks_like_v1_json(data):
+        return Encoding.JSON_V1
+    return enc
+
+
+def detect_framing(data: bytes) -> Encoding:
+    """:func:`detect` without the dialect sniff: JSON of either dialect
+    comes back as ``JSON_V2``. It reads the first byte, the head and tail
+    of the body and, after an 0x0A lead, the proto3 frame headers, never
+    the whole body: the router for a JSON parser that tells v1 apart
+    itself, as the native tokenizer does by structure. Raises ValueError
+    for the payloads :func:`detect` refuses."""
     if not data:
         raise ValueError("empty payload")
     first = data[0]
@@ -104,12 +117,12 @@ def detect(data: bytes) -> Encoding:
         if _plausible_proto3_frame(data):
             return Encoding.PROTO3
         if _looks_like_json(data):
-            return Encoding.JSON_V1 if _looks_like_v1_json(data) else Encoding.JSON_V2
+            return Encoding.JSON_V2
         return Encoding.PROTO3
     if first in (0x5B, 0x7B) or (
         first in (0x20, 0x09, 0x0D) and _looks_like_json(data)
     ):
-        return Encoding.JSON_V1 if _looks_like_v1_json(data) else Encoding.JSON_V2
+        return Encoding.JSON_V2
     if first == 0x0C:
         return Encoding.THRIFT
     raise ValueError(f"unrecognized span payload (first byte 0x{first:02x})")

@@ -7,8 +7,8 @@ system toolchain and loaded via ctypes — no pip dependencies.
 
 Graceful degradation is part of the contract: if no compiler is
 available, or the payload uses features the fast path doesn't cover
-(escaped strings, unknown kinds), callers fall back to the pure-Python
-codec, which is the semantic reference.
+(escaped strings, unknown kinds, the JSON v1 dialect), callers fall
+back to the pure-Python codec, which is the semantic reference.
 """
 
 from __future__ import annotations
@@ -30,6 +30,9 @@ logger = logging.getLogger(__name__)
 _DIR = os.path.dirname(__file__)
 _SRC = os.path.join(_DIR, "span_json.c")
 _BUILD_DIR = os.path.join(_DIR, "build")
+
+#: span_json.c's ERR_V1: the JSON tokenizer met a span in v1's shape
+_ERR_V1 = -4
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -200,6 +203,9 @@ class NativeVocab:
     insertion journal into the Python Vocab and asserts the ids line up,
     so everything downstream (lookup tables, snapshots) keeps working.
     Not thread-safe: callers serialize parse+sync (the store does).
+
+    ``dialect_fallbacks`` counts the payloads that :func:`parse_spans`
+    handed back as JSON v1 while parsing against these tables.
     """
 
     def __init__(self, vocab) -> None:
@@ -217,6 +223,7 @@ class NativeVocab:
             raise MemoryError("zt_vocab_new failed")
         self._drain_buf = np.zeros(1 << 20, np.uint8)
         self._pair_buf = np.zeros(1 << 16, np.uint64)
+        self.dialect_fallbacks = 0
 
     @property
     def overflow(self) -> int:
@@ -321,27 +328,29 @@ def parse_spans(
     data: bytes, cap: Optional[int] = None, nvocab: Optional[NativeVocab] = None
 ) -> Optional[ParsedColumns]:
     """Parse a JSON v2 span array OR a proto3 ``ListOfSpans`` into
-    columns; None => use the Python codec (parse error, unsupported
-    feature, or no native lib). Format is sniffed the same way the
-    object-path codec dispatcher does: '[' selects JSON, a 0x0A first
-    byte (ListOfSpans field-1 tag) selects proto3.
+    columns; None => use the Python codec (a JSON v1 body, parse error,
+    unsupported feature, or no native lib).
+
+    Routing is by framing alone (``codec.detect_framing``): a JSON opener
+    (``[``/``{``, whitespace-led included) goes to the JSON tokenizer; an
+    0x0A first byte (the ListOfSpans field-1 tag, and also a newline)
+    goes to proto3 if the proto3 frame headers consume the payload
+    exactly, else to the tokenizer; anything else returns None. The body
+    is never scanned for v1 markers: the tokenizer meets v1's shape
+    (``binaryAnnotations``, an annotation with an ``endpoint``) in its own
+    pass and refuses the body, counted in ``nvocab.dialect_fallbacks``.
 
     With ``nvocab``, interning happens inside the parse (the ``*_id``
     columns are filled); the caller must hold the store's intern lock and
-    call ``nvocab.sync()`` afterwards.
+    call ``nvocab.sync()`` afterwards. A refused body interns nothing.
     """
     lib = _load()
     if lib is None:
         return None
-    # Route by the SAME structural sniff the object-path dispatcher uses:
-    # 0x0A is ambiguous (proto3 field-1 tag AND a newline), and a naive
-    # first-byte test misroutes e.g. a ListOfSpans whose first span is
-    # 0x5B ('[') bytes long. codec.detect resolves it with a frame walk
-    # over the proto3 headers (O(#spans), no payload copy).
     from zipkin_tpu.model import codec as _codec
 
     try:
-        enc = _codec.detect(data)
+        enc = _codec.detect_framing(data)
     except ValueError:
         return None
     if enc is _codec.Encoding.JSON_V2:
@@ -401,6 +410,8 @@ def parse_spans(
         out.svc_id = None
         n = fn_plain(data, len(data), cap, *common)
     if n < 0:
+        if n == _ERR_V1 and nvocab is not None:
+            nvocab.dialect_fallbacks += 1
         return None
     out.n = int(n)
     return out

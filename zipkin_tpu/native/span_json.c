@@ -14,6 +14,14 @@
  * negative error and the caller falls back to the python codec, which
  * produces the authoritative error message.
  *
+ * Dialect: the caller routes by framing alone and never scans the body
+ * for v1 markers; the tokenizer tells v1 from v2 in the pass it already
+ * makes. A span-level "binaryAnnotations" key, or an "annotations"
+ * element with an "endpoint" member, is v1's shape and returns ERR_V1 as
+ * soon as it is seen. The strings inside tags, names and values are never
+ * read for it, so a v2 tag key that happens to be "binaryAnnotations" or
+ * "endpoint" stays v2.
+ *
  * Built with: cc -O2 -fPIC -shared (see build.py); called via ctypes.
  */
 
@@ -52,6 +60,7 @@ typedef struct {
 #define ERR_TRUNC  (-1)
 #define ERR_SYNTAX (-2)
 #define ERR_CAP    (-3)
+#define ERR_V1     (-4) /* a JSON v1 span: the python codec decodes it */
 
 static void skip_ws(cursor_t *c) {
   while (c->pos < c->n) {
@@ -283,6 +292,51 @@ static int read_bool(cursor_t *c, uint8_t *out) {
   return ERR_SYNTAX;
 }
 
+/* one "annotations" element object, at its '{': v2's carry timestamp and
+ * value; a member "endpoint" exists only in v1 */
+static int read_annotation(cursor_t *c) {
+  c->pos++;
+  skip_ws(c);
+  if (c->pos < c->n && c->buf[c->pos] == '}') { c->pos++; return 0; }
+  for (;;) {
+    skip_ws(c);
+    uint32_t koff, klen; int esc;
+    int rc = read_string_slice(c, &koff, &klen, &esc); if (rc) return rc;
+    if (!esc && key_is(c->buf, koff, klen, "endpoint")) return ERR_V1;
+    skip_ws(c);
+    if (c->pos >= c->n || c->buf[c->pos] != ':') return ERR_SYNTAX;
+    c->pos++;
+    rc = skip_value(c); if (rc) return rc;
+    skip_ws(c);
+    if (c->pos >= c->n) return ERR_TRUNC;
+    if (c->buf[c->pos] == ',') { c->pos++; continue; }
+    if (c->buf[c->pos] == '}') { c->pos++; return 0; }
+    return ERR_SYNTAX;
+  }
+}
+
+/* the "annotations" value: walked element by element for v1's shape;
+ * anything but an array is skipped as before */
+static int read_annotations(cursor_t *c) {
+  skip_ws(c);
+  if (c->pos >= c->n) return ERR_TRUNC;
+  if (c->buf[c->pos] != '[') return skip_value(c);
+  c->pos++;
+  skip_ws(c);
+  if (c->pos < c->n && c->buf[c->pos] == ']') { c->pos++; return 0; }
+  for (;;) {
+    skip_ws(c);
+    if (c->pos >= c->n) return ERR_TRUNC;
+    int rc = c->buf[c->pos] == '{' ? read_annotation(c) : skip_value(c);
+    if (rc) return rc;
+    skip_ws(c);
+    if (c->pos >= c->n) return ERR_TRUNC;
+    if (c->buf[c->pos] == ',') { c->pos++; continue; }
+    if (c->buf[c->pos] == ']') { c->pos++; return 0; }
+    return ERR_SYNTAX;
+  }
+}
+
 static int parse_span(cursor_t *c, columns_t *cols, long i) {
   skip_ws(c);
   if (c->pos >= c->n || c->buf[c->pos] != '{') return ERR_SYNTAX;
@@ -345,6 +399,10 @@ static int parse_span(cursor_t *c, columns_t *cols, long i) {
       rc = read_bool(c, &cols->shared_flag[i]);
     } else if (key_is(b, koff, klen, "debug")) {
       rc = read_bool(c, &cols->debug_flag[i]);
+    } else if (key_is(b, koff, klen, "annotations")) {
+      rc = read_annotations(c);
+    } else if (key_is(b, koff, klen, "binaryAnnotations")) {
+      return ERR_V1;
     } else {
       rc = skip_value(c);
     }
@@ -633,7 +691,10 @@ long zt_vocab_overflow(void *vp) {
 }
 
 /* parse + intern in one pass: same as zt_parse_spans plus id columns.
- * vocab may be NULL (ids left zero). */
+ * vocab may be NULL (ids left zero). Interning starts only once the whole
+ * body has parsed, so a payload refused with any error code (ERR_V1
+ * included) leaves the tables, and the Python Vocab they mirror, as they
+ * were. */
 long zt_parse_spans_interned(
     const uint8_t *buf, size_t n, long cap, void *vocabp,
     uint32_t *tl0, uint32_t *tl1, uint32_t *th0, uint32_t *th1,

@@ -600,15 +600,15 @@ class TpuStorage(
 
     def ingest_json_fast(self, data: bytes, sampler=None):
         """Line-rate ingest: raw JSON v2 OR proto3 ``ListOfSpans`` bytes
-        -> device aggregates via the native columnar parser (format
-        sniffed by first byte), skipping Span objects for the bulk of
-        the stream. A trace-affine 1/N sample IS archived at full fidelity
-        (the parser records each span's byte extent; sampled slices are
-        re-decoded by the reference codec), so ``/api/v2/trace/{id}`` and
-        search stay alive in fast mode — the round-1 gap where the
-        benchmark configuration and the queryable configuration were
-        different systems. N = TPU_FAST_ARCHIVE_SAMPLE (default 64,
-        0 disables).
+        -> device aggregates via the native columnar parser (routed by
+        framing; a JSON v1 body is handed back), skipping Span objects
+        for the bulk of the stream. A trace-affine 1/N sample IS archived
+        at full fidelity (the parser records each span's byte extent;
+        sampled slices are re-decoded by the reference codec), so
+        ``/api/v2/trace/{id}`` and search stay alive in fast mode — the
+        round-1 gap where the benchmark configuration and the queryable
+        configuration were different systems. N = TPU_FAST_ARCHIVE_SAMPLE
+        (default 64, 0 disables).
 
         Returns (accepted, sample_dropped), or None when the native path
         can't take this payload (caller falls back to the object path).
@@ -639,7 +639,10 @@ class TpuStorage(
                 self._nvocab.ensure_synced()
                 parsed = native.parse_spans(data, nvocab=self._nvocab)
                 if parsed is None:
-                    parse.drop()  # not a payload for this path
+                    # not a payload for this path (JSON v1, thrift, a
+                    # parse error): the refused parse interned nothing,
+                    # so the C tables and the Vocab stay in step
+                    parse.drop()
                     return None
                 self._nvocab.sync()
             n = parsed.n
@@ -1822,6 +1825,12 @@ class TpuStorage(
             # the Python journal so the C counter is separate
             "nativeVocabOverflow": (
                 self._nvocab.overflow if self._nvocab is not None else 0
+            ),
+            # payloads the native tokenizer handed back as JSON v1 (they
+            # took the object path): how often fast ingest meets v1
+            "fastIngestDialectFallbacks": (
+                self._nvocab.dialect_fallbacks
+                if self._nvocab is not None else 0
             ),
             # boot-time restore gauges (restoreMs / walReplayBatches /
             # walReplayMs): how much recovery cost the last boot
